@@ -404,14 +404,15 @@ def main():
     if args.spec_k:
         matrix += [(1, 0, args.spec_k), (1, args.prefill_chunk, args.spec_k)]
     if args.tp > 1:
-        if len(jax.devices()) >= args.tp:
-            matrix += [(args.tp, 0, 0), (args.tp, args.prefill_chunk, 0)]
-            if args.spec_k:
-                matrix += [(args.tp, 0, args.spec_k),
-                           (args.tp, args.prefill_chunk, args.spec_k)]
-        else:
-            print(f"[skip] tp={args.tp}: only {len(jax.devices())} device(s); "
-                  f"rerun with --force-host-devices {max(8, args.tp)}")
+        if len(jax.devices()) < args.tp:
+            raise SystemExit(
+                f"tp={args.tp} needs {args.tp} devices, found "
+                f"{len(jax.devices())}; on CPU pass --force-host-devices "
+                f"{max(8, args.tp)}")
+        matrix += [(args.tp, 0, 0), (args.tp, args.prefill_chunk, 0)]
+        if args.spec_k:
+            matrix += [(args.tp, 0, args.spec_k),
+                       (args.tp, args.prefill_chunk, args.spec_k)]
 
     rows = [bench_static(base_cfg, params, stream)]
     for tp, chunk, spec_k in matrix:

@@ -20,6 +20,7 @@ leaves and the site metadata rides in the manifest's ``extra`` dict.
 from __future__ import annotations
 
 import re
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -98,6 +99,14 @@ def _site_cfg_if_uniform(cfg, role: str, layered: bool) -> Optional[NumericsConf
     return first
 
 
+@partial(jax.jit, static_argnames=("spec", "pack"))
+def _encode_leaf(leaf, spec, pack: bool):
+    """One fused elementwise pass from the stored dtype to patterns, so no
+    f32 or int32 copy of a stacked weight is ever materialized."""
+    bits = encode(leaf.astype(jnp.float32), spec)
+    return pack16(bits) if pack else bits
+
+
 def quantize_params(cfg, params, *, pack: bool = True):
     """Encode policy-selected weights to posit patterns once.
 
@@ -122,9 +131,7 @@ def quantize_params(cfg, params, *, pack: bool = True):
         if site_cfg is None or site_cfg.mode not in _POSIT_MODES:
             return leaf
         spec = site_cfg.spec
-        bits = encode(jnp.asarray(leaf, jnp.float32), spec)
-        if pack and spec.n <= 16:
-            bits = pack16(bits)
+        bits = _encode_leaf(leaf, spec, pack and spec.n <= 16)
         meta[path] = {
             "role": role,
             "mode": site_cfg.mode,

@@ -24,8 +24,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.parallel.sharding import current_mesh
 
-from .compat import CompilerParams
-
 DEFAULT_BLOCK = 512
 
 
@@ -96,7 +94,7 @@ def decode_attention(q, k, v, lengths, *, blk: int = DEFAULT_BLOCK, interpret: b
             pltpu.VMEM((kv, group), jnp.float32),
             pltpu.VMEM((kv, group, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q, k, v)
@@ -199,7 +197,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables, lengths,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, block_tables, q, k_pool, v_pool)
@@ -252,8 +250,6 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths, mesh,
     Requires kv % tp == 0 (the caller falls back to the GSPMD gather
     path, with the pool sharded on positions via ``seq_tp``, otherwise).
     """
-    from jax.experimental.shard_map import shard_map
-
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
 
@@ -264,12 +260,12 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths, mesh,
         return paged_decode_attention_ref(q_l, kp_l, vp_l, bt, lens)
 
     pool_spec = P(None, None, "model", None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, "model", None), pool_spec, pool_spec,
                   P(None, None), P(None)),
         out_specs=P(None, "model", None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_pool, v_pool, block_tables, lengths)
 
 

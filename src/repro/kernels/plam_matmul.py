@@ -25,8 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.numerics import PositSpec
 from repro.numerics.posit import I32, U32, decode_fields
@@ -68,17 +67,17 @@ def _plam_matmul_kernel(a_ref, b_ref, o_ref, *, spec: PositSpec, bk: int):
     la_pre = la - I32(_BIAS)  # pre-subtract the bias once per A element
 
     acc = o_ref[...]
-
-    def body(k, acc):
+    # Static unroll over k: Mosaic lowers a static [bm, 1] lane slice,
+    # but not a column read at a loop-carried index.  k ascends, so the
+    # f32 accumulation order is that of ``ref.plam_matmul_seqref``.
+    for k in range(bk):
         # [bm,1] x [1,bn] broadcasts: per pair ONE add + bitcast (+mask).
-        lsum = la_pre[:, k][:, None] + lb[k, :][None, :]
-        sgn = sa[:, k][:, None] ^ sb[k, :][None, :]
+        lsum = la_pre[:, k:k + 1] + lb[k:k + 1, :]
+        sgn = sa[:, k:k + 1] ^ sb[k:k + 1, :]
         bits = sgn | lsum.astype(U32)
         val = jax.lax.bitcast_convert_type(bits, jnp.float32)
-        ok = va[:, k][:, None] & vb[k, :][None, :]
-        return acc + jnp.where(ok, val, jnp.float32(0))
-
-    acc = jax.lax.fori_loop(0, bk, body, acc)
+        ok = va[:, k:k + 1] & vb[k:k + 1, :]
+        acc = acc + jnp.where(ok, val, jnp.float32(0))
     o_ref[...] = acc
 
 
@@ -129,7 +128,7 @@ def plam_matmul(
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

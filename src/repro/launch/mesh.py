@@ -8,12 +8,14 @@ from __future__ import annotations
 
 import jax
 
+from repro.parallel.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1):
@@ -21,7 +23,7 @@ def make_host_mesh(*, data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_dims(mesh) -> dict:

@@ -211,8 +211,10 @@ def main():
 
     from repro.configs import ARCHS, get_config
     from repro.core.policy import describe, load_policy_arg, parse_policy
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import ContinuousBatchingEngine, build_engine
 
+    enable_compile_cache()
     opts = options_from_args(args)
 
     if args.arch not in ARCHS:

@@ -189,7 +189,10 @@ def encode_fields(sign, scale, frac, fbits, spec: PositSpec, fbits_static=None):
     body_pre = _shl(regime, avail) + kept
     inc = round_bit & (sticky | ((body_pre & U32(1)) == U32(1))).astype(U32)
     body = body_pre + inc
-    body = jnp.minimum(body, U32(spec.maxpos_body))  # carry past maxpos saturates
+    # a carry past maxpos saturates; body <= maxpos_body + 1 == 2^(n-1),
+    # so subtracting bit n-1 clamps it without an unsigned min, which
+    # Mosaic cannot lower
+    body = body - (body >> U32(n - 1))
     body = jnp.where(too_big, U32(spec.maxpos_body), body)
     body = jnp.where(too_small, U32(1), body)  # minpos: never round to zero
 

@@ -3,6 +3,7 @@ from .sharding import (  # noqa: F401
     constrain,
     current_mesh,
     expert_parallel_rules,
+    make_mesh,
     param_shardings,
     pspec,
     spec_for_param,

@@ -13,7 +13,7 @@ import re
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical -> physical mesh axes
 _LOGICAL = {
@@ -27,11 +27,22 @@ _LOGICAL = {
 _MESH: contextvars.ContextVar[Optional[Mesh]] = contextvars.ContextVar("repro_mesh", default=None)
 
 
+def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the rules below are GSPMD
+    constraints, which jax refuses on the default Explicit axes."""
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
+
+
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
     tok = _MESH.set(mesh)
     try:
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else contextlib.nullcontext():
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _MESH.reset(tok)
@@ -135,11 +146,27 @@ def sanitize(mesh: Mesh, dims, shape):
     return tuple(out)
 
 
-def param_shardings(mesh: Mesh, params, rules=None):
-    """NamedSharding pytree for a parameter pytree."""
+def kernel_weight_dims(mesh: Mesh, shape) -> tuple:
+    """Layout of a weight that the Pallas PLAM kernel reads: output
+    columns over the model axis, replicated when they do not divide it.
+    GSPMD cannot partition the kernel, so ``kernels.ops.plam_dense``
+    shard_maps it over this layout, whatever the weight's Megatron role."""
+    return sanitize(mesh, (None,) * (len(shape) - 1) + ("model",), shape)
+
+
+def param_shardings(mesh: Mesh, params, rules=None, kernel_weights=()):
+    """NamedSharding pytree for a parameter pytree.
+
+    ``kernel_weights`` holds the '/'-joined paths of prequantized leaves
+    served through the PLAM kernel; they take :func:`kernel_weight_dims`
+    instead of their rule.
+    """
     def one(path, leaf):
-        dims = spec_for_param(_path_str(path), leaf.ndim, rules)
-        dims = sanitize(mesh, dims, leaf.shape)
+        path = _path_str(path)
+        if path in kernel_weights:
+            dims = kernel_weight_dims(mesh, leaf.shape)
+        else:
+            dims = sanitize(mesh, spec_for_param(path, leaf.ndim, rules), leaf.shape)
         return NamedSharding(mesh, pspec(mesh, dims))
 
     return jax.tree_util.tree_map_with_path(one, params)

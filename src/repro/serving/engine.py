@@ -47,7 +47,12 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import ModelAPI, build
-from repro.parallel.sharding import paged_pool_spec, param_shardings, use_mesh
+from repro.parallel.sharding import (
+    make_mesh,
+    paged_pool_spec,
+    param_shardings,
+    use_mesh,
+)
 
 from .kv_cache import BlockAllocator, SCRATCH_BLOCK, padded_prompt_len
 from .observability import (
@@ -57,6 +62,17 @@ from .observability import (
     phase_annotation,
 )
 from .scheduler import Request, RequestState, Scheduler
+
+
+# Model steps round each bf16 value where the program does.  By default
+# XLA may keep a bf16 intermediate in f32 inside a fusion, so rounding
+# would follow fusion and collective boundaries, and tensor parallelism
+# moves those: a tp=N step could then round elsewhere than tp=1.
+STEP_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
+
+def _step_jit(fn, **kw):
+    return jax.jit(fn, compiler_options=STEP_COMPILER_OPTIONS, **kw)
 
 
 @dataclasses.dataclass
@@ -189,8 +205,8 @@ class Engine:
             from repro.core.prequant import quantize_params
 
             self.params, self.prequant_meta = quantize_params(cfg, self.params)
-        self._prefill = jax.jit(self.api.prefill)
-        self._decode = jax.jit(self.api.decode_step)
+        self._prefill = _step_jit(self.api.prefill)
+        self._decode = _step_jit(self.api.decode_step)
         self._enc_cache = None  # encdec: encoder output, fixed per generate()
         self.stats = ServeStats()
         # same registry surface as the continuous engine (sourced subset:
@@ -293,7 +309,7 @@ class Engine:
             if self._enc_cache is None:
                 from repro.models import encdec  # lazy to avoid cycle
 
-                self._enc_cache = jax.jit(partial(encdec.encode, self.cfg))(
+                self._enc_cache = _step_jit(partial(encdec.encode, self.cfg))(
                     self.params, prompt_batch["frames"]
                 )
             return {"kv_caches": caches, "enc_out": self._enc_cache}
@@ -470,18 +486,11 @@ class ContinuousBatchingEngine:
                     "on CPU force more with "
                     "XLA_FLAGS=--xla_force_host_platform_device_count=N"
                 )
-            self._mesh = jax.make_mesh((1, pcfg.tp), ("data", "model"))
+            self._mesh = make_mesh(
+                (1, pcfg.tp), ("data", "model"), devices=jax.devices()[: pcfg.tp]
+            )
 
-        self.params = (
-            params
-            if params is not None
-            else self.api.init(key if key is not None else jax.random.PRNGKey(0))
-        )
-        self.prequant_meta = {}
-        if pcfg.prequantize:
-            from repro.core.prequant import quantize_params
-
-            self.params, self.prequant_meta = quantize_params(cfg, self.params)
+        self.params, self.prequant_meta = self._init_params(params, key)
 
         bs, nb = pcfg.block_size, pcfg.num_blocks
         # the block table is wide enough for the worst-case speculative
@@ -490,15 +499,15 @@ class ContinuousBatchingEngine:
         # must land in the sequence's own (reserved) blocks — never be
         # clamped back onto committed positions by dynamic_update_slice
         self.max_blocks_per_seq = -(-(pcfg.max_seq_len + pcfg.spec_k) // bs)
-        dtype = jnp.dtype(pcfg.cache_dtype)
-        self._k_pool, self._v_pool = self.api.paged_pool_init(nb, bs, dtype)
+        pool_init = partial(
+            self.api.paged_pool_init, nb, bs, jnp.dtype(pcfg.cache_dtype)
+        )
+        pool_sharding = None
         if self._mesh is not None:
-            self.params = jax.device_put(
-                self.params, param_shardings(self._mesh, self.params)
-            )
-            pool_sharding = paged_pool_spec(self._mesh, self._k_pool.shape)
-            self._k_pool = jax.device_put(self._k_pool, pool_sharding)
-            self._v_pool = jax.device_put(self._v_pool, pool_sharding)
+            pool_shape = jax.eval_shape(pool_init)[0].shape
+            pool_sharding = paged_pool_spec(self._mesh, pool_shape)
+            pool_sharding = (pool_sharding, pool_sharding)
+        self._k_pool, self._v_pool = jax.jit(pool_init, out_shardings=pool_sharding)()
         self.allocator = BlockAllocator(nb, bs, prefix_cache=pcfg.prefix_cache)
         self._clock = pcfg.clock if pcfg.clock is not None else time.monotonic
         self.scheduler = Scheduler(
@@ -511,13 +520,13 @@ class ContinuousBatchingEngine:
         )
 
         donate = (2, 3) if jax.default_backend() != "cpu" else ()
-        self._prefill = jax.jit(self.api.paged_prefill, donate_argnums=donate)
+        self._prefill = _step_jit(self.api.paged_prefill, donate_argnums=donate)
         self._prefill_chunk = (
-            jax.jit(self.api.paged_prefill_chunk, donate_argnums=donate)
+            _step_jit(self.api.paged_prefill_chunk, donate_argnums=donate)
             if self.api.paged_prefill_chunk is not None
             else None
         )
-        self._decode = jax.jit(
+        self._decode = _step_jit(
             partial(self.api.paged_decode_step, use_kernel=pcfg.use_kernel),
             donate_argnums=donate,
         )
@@ -533,7 +542,7 @@ class ContinuousBatchingEngine:
                 if isinstance(pcfg.spec_draft, str)
                 else pcfg.spec_draft
             )
-            self._score = jax.jit(self.api.paged_score_tokens, donate_argnums=donate)
+            self._score = _step_jit(self.api.paged_score_tokens, donate_argnums=donate)
         # zero freed blocks that still hold written-but-never-committed
         # K/V (rolled-back draft tails, prefill padding) before the
         # allocator can hand them to another sequence; the id row is
@@ -581,6 +590,45 @@ class ContinuousBatchingEngine:
         self.metrics = MetricsRegistry()
         self._wire_metrics()
         self.stats._registry = self.metrics
+
+    def _init_params(self, params, key):
+        """Return ``(params, prequant_meta)`` placed in their shardings.
+
+        Without ``params``, weights are initialised from ``key`` — and
+        prequantized, under ``prequantize`` — in ONE jitted program whose
+        outputs are the final shardings: no float copy of a quantized
+        weight outlives its encode, and under ``tp > 1`` every device
+        builds only its own shard (nothing is staged on device 0).
+        """
+        from repro.core.prequant import quantize_params
+
+        meta = {}
+
+        def prepare(p):
+            if not self.pcfg.prequantize:
+                return p
+            p, m = quantize_params(self.cfg, p)
+            meta.update(m)
+            return p
+
+        def shardings(tree):
+            if self._mesh is None:
+                return None
+            kernel = {p for p, m in meta.items() if m["mode"] == "plam_sim"}
+            return param_shardings(self._mesh, tree, kernel_weights=kernel)
+
+        if params is None:
+            key = key if key is not None else jax.random.PRNGKey(0)
+
+            def build_params(k):
+                return prepare(self.api.init(k))
+
+            out = shardings(jax.eval_shape(build_params, key))
+            return jax.jit(build_params, out_shardings=out)(key), meta
+        params = prepare(params)
+        if self._mesh is not None:
+            params = jax.device_put(params, shardings(params))
+        return params, meta
 
     @property
     def current_step(self) -> int:

@@ -24,10 +24,10 @@ from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.launch.dryrun import build_cell
 from repro.launch.hlo_analysis import analyze
-from repro.parallel.sharding import use_mesh
+from repro.parallel.sharding import make_mesh, use_mesh
 
 results = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for arch in ["yi-6b", "granite-moe-1b-a400m", "mamba2-780m"]:
     cfg = get_config(arch).reduced()
     shape = ShapeSpec("ci", seq_len=64, global_batch=4, kind="train")

@@ -1,8 +1,14 @@
 """Prequantized posit weight storage: quantize_params -> nmatmul
 pattern path -> serving engines -> checkpoint round trip."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.configs.base import ModelConfig
 from repro.core.modes import NumericsConfig, nmatmul
@@ -132,3 +138,73 @@ def test_prequantized_checkpoint_round_trip(tmp_path):
     # restored patterns still serve
     logits, _ = api.prefill(restored, {"tokens": jnp.zeros((1, 8), jnp.int32)})
     assert np.isfinite(np.asarray(logits)).all()
+
+
+_TP_PLAM_SCRIPT = textwrap.dedent("""
+    import sys
+    import numpy as np, jax, jax.numpy as jnp
+    from repro.configs.base import ModelConfig
+    from repro.core.modes import NumericsConfig
+    from repro.serving import ContinuousBatchingEngine, PagedServeConfig
+
+    tp = int(sys.argv[1])
+    assert len(jax.devices()) >= tp, jax.devices()
+    # Yi-6B's dtypes and head layout (32 q heads over 4 kv heads, so the
+    # decode kernel is head-sharded at tp=4); vocab 61 is odd, so the
+    # lm_head takes the replicated-weight branch
+    cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
+        n_heads=32, n_kv=4, head_dim=2, d_ff=64, vocab=61,
+        numerics=NumericsConfig(mode="plam_sim", n=16, es=1),
+        act_dtype="bfloat16", param_dtype="bfloat16")
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 61, n).tolist() for n in (3, 9)]
+
+    def stream(tp):
+        eng = ContinuousBatchingEngine(cfg, key=jax.random.PRNGKey(0),
+            pcfg=PagedServeConfig(block_size=4, num_blocks=64, max_slots=3,
+                                  max_seq_len=32, tp=tp, prequantize=True))
+        reqs = [eng.submit(p, max_new_tokens=5, arrival_step=i)
+                for i, p in enumerate(prompts)]
+        done = eng.run()
+        return [done[r.rid] for r in reqs], eng
+
+    base, _ = stream(1)
+    got, eng = stream(tp)
+    wo = eng.params["layers"]["attn"]["wo"]
+    assert wo.dtype == jnp.int16, wo.dtype
+    # kernel-served pattern weights are column-sharded, whatever their
+    # Megatron role
+    assert tuple(wo.sharding.spec)[-1] == "model", wo.sharding.spec
+    assert base == got, f"tp{tp} prequantized plam diverged: {base} vs {got}"
+    print("TP-PLAM-IDENTICAL-OK")
+""")
+
+
+def _run_tp_plam(tp: int):
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4")
+    env["JAX_PLATFORMS"] = "cpu"
+    src_dir = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.path.abspath(src_dir) + os.pathsep + env.get(
+        "PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", _TP_PLAM_SCRIPT, str(tp)],
+                          env=env, capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
+    assert "TP-PLAM-IDENTICAL-OK" in proc.stdout
+
+
+@pytest.mark.slow
+def test_tp2_prequantized_plam_token_identical_forced_devices():
+    """Prequantized plam_sim serves through the Pallas PLAM kernel, which
+    is shard_mapped over output columns under tensor parallelism: tp=2
+    is greedy-token-identical to tp=1 in bf16.  Runs in a subprocess
+    because the forced device count must be set before jax initializes."""
+    _run_tp_plam(2)
+
+
+@pytest.mark.slow
+def test_tp4_prequantized_plam_token_identical_forced_devices():
+    """As the tp=2 case, with the decode-attention kernel head-sharded
+    (kv=1 per device), the layout Yi-6B serves with on four chips."""
+    _run_tp_plam(4)

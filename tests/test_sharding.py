@@ -10,6 +10,7 @@ from repro.parallel.sharding import (
     pspec,
     sanitize,
     spec_for_param,
+    make_mesh,
     use_mesh,
 )
 
@@ -27,11 +28,11 @@ def test_spec_rules():
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_sanitize_drops_indivisible():
-    mesh = jax.make_mesh((1, 2), ("data", "model")) if len(jax.devices()) >= 2 \
+    mesh = make_mesh((1, 2), ("data", "model")) if len(jax.devices()) >= 2 \
         else _mesh()
     msz = mesh.shape["model"]
     dims = sanitize(mesh, ("model", None), (49155, 64))
