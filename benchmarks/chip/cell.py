@@ -1,0 +1,64 @@
+"""A cell's files: its entry in BENCHMARK.json, configuration and mix.
+
+Everything a cell needs is found by name: the workload entry names a
+configuration (``configs/<config>.json``) and a traffic mix
+(``traffic/<mix>.json``); each per-layer metric is
+``metrics/<metric>.py``.  A new cell needs only new files and entries.
+"""
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import pathlib
+
+CHIP = pathlib.Path(__file__).resolve().parent
+ROOT = CHIP.parents[1]
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict
+    mix_path: pathlib.Path
+    end_to_end: list  # BENCHMARK.json metric entries reported with --trace 0
+    per_layer: list  # ... and with --trace 1
+
+
+def _applies(metric: dict, workload: str) -> bool:
+    return "workloads" not in metric or workload in metric["workloads"]
+
+
+def load_config(path) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_cell(workload: str, bench_path=ROOT / "BENCHMARK.json") -> Cell:
+    with open(bench_path) as f:
+        bench = json.load(f)
+    entry = next((w for w in bench["workloads"] if w["name"] == workload), None)
+    if entry is None:
+        names = [w["name"] for w in bench["workloads"]]
+        raise SystemExit(f"unknown workload {workload!r}; known: {names}")
+    conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    return Cell(
+        name=workload,
+        chips=int(entry["chips"]),
+        config=load_config(ROOT / conf["file"]),
+        mix_path=CHIP / "traffic" / f"{entry['traffic']}.json",
+        end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
+        per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
+    )
+
+
+def load_metric(name: str):
+    """``metrics/<name>.py``: its ``read(ctx)`` returns a number or None."""
+    path = CHIP / "metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        "chip_metric_" + name.replace(".", "_").replace("-", "_"), path
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
