@@ -18,7 +18,9 @@ Four chips: the ``plam_sim`` engine at ``tp=4`` and at ``tp=1`` serve the
 same requests, and their greedy tokens must be identical.
 
 Earlier lines report the device, parameter bytes, compile and wall
-seconds per phase, every request's tokens and peak device memory.  The
+seconds per phase, the host wall of each engine step and the host
+seconds of each step phase, every request's tokens and peak device
+memory.  The
 last line is one JSON object, printed only when every check passed.  The
 script exits non-zero without that line when JAX finds no TPU.
 """
@@ -190,8 +192,12 @@ def serve(label, cfg, opts, reqs, clock, devices, kernels=()):
     tokens = [done[h.rid] for h in handles]
     print(f"[{label}] engine_steps={eng.stats.steps} "
           f"generated={eng.stats.generated_tokens}", flush=True)
-    # host wall per engine step, first compiles included
+    # host wall per engine step, first compiles included, and the host
+    # seconds of each step phase: summed, and its longest occurrence
     print(f"[{label}] step_wall_s={[round(s, 3) for s in eng.stats.step_latency_s]}",
+          flush=True)
+    print(f"[{label}] host_s={ {p: round(s, 4) for p, s in eng.stats.host_s.items()} } "
+          f"host_max_s={ {p: round(s, 4) for p, s in eng.stats.host_max_s.items()} }",
           flush=True)
     for i, (p, t) in enumerate(zip(reqs, tokens)):
         print(f"[{label}] req{i} prompt_len={len(p)} tokens={t}", flush=True)

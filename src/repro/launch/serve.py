@@ -37,10 +37,14 @@ Observability (see docs/observability.md): tracing is on by default;
 ``--trace-out PATH`` writes the engine trace after the run (Chrome
 trace_event JSON when PATH ends in ``.json`` — load it in Perfetto —
 JSON-lines otherwise), ``--metrics-out PATH`` writes a Prometheus text
-snapshot, and ``--profile`` wraps each engine phase in a
-``jax.profiler`` TraceAnnotation for profiler captures.
+snapshot (each engine phase's host seconds among it), ``--profile``
+wraps each engine phase in a ``jax.profiler`` TraceAnnotation
+``serve.<phase>`` for profiler captures, and ``--profile-dir DIR`` turns
+that on and captures the run with ``jax.profiler.trace(DIR)`` (open the
+``.xplane.pb`` under DIR in TensorBoard or Perfetto).
 """
 import argparse
+import contextlib
 import os
 import warnings
 
@@ -111,6 +115,9 @@ def make_parser() -> argparse.ArgumentParser:
                     help="annotate engine phases with jax.profiler "
                          "TraceAnnotations (visible inside a profiler "
                          "capture)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="capture the run with jax.profiler.trace(DIR); "
+                         "implies --profile")
     ap.add_argument("--force-host-devices", type=int, default=0,
                     help="force N host (CPU) devices via XLA_FLAGS — must be "
                          "set before jax initializes, so it only works as a "
@@ -179,7 +186,7 @@ def options_from_args(args):
         tp=args.tp,
         prefill_chunk=args.prefill_chunk,
         prequantize=args.prequantized,
-        profile=args.profile,
+        profile=args.profile or bool(args.profile_dir),
         **legacy_vals,
     )
     fields = {f.name: f for f in dataclasses.fields(ServeOptions)}
@@ -242,7 +249,8 @@ def main():
             rng.integers(0, cfg.vocab, args.prompt_len).tolist(),
             arrival_step=i, **opts.submit_kwargs())
             for i in range(args.batch)]
-        done = eng.run()
+        with _capture(args):
+            done = eng.run()
         spec = (f" spec_k={opts.spec_k} "
                 f"accept={eng.stats.acceptance_rate():.1%} "
                 f"tok/verify={eng.stats.tokens_per_verify_step():.2f}"
@@ -282,13 +290,23 @@ def main():
     assert not isinstance(eng, ContinuousBatchingEngine)
     prompts = {"tokens": jnp.asarray(
         rng.integers(0, cfg.vocab, (args.batch, args.prompt_len)).astype(np.int32))}
-    out = eng.generate(prompts, opts.static())
+    with _capture(args):
+        out = eng.generate(prompts, opts.static())
     print(f"arch={cfg.name} numerics={numerics_label!r} "
           f"step_p50={eng.stats.latency_p50() * 1e3:.1f}ms "
           f"step_p95={eng.stats.latency_p95() * 1e3:.1f}ms")
     for i, row in enumerate(np.asarray(out)):
         print(f"batch[{i}]: {row.tolist()}")
     _write_artifacts(args, eng)
+
+
+def _capture(args):
+    """``jax.profiler.trace(--profile-dir)`` around the run, if asked."""
+    if not args.profile_dir:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.profiler.trace(args.profile_dir)
 
 
 def _write_artifacts(args, eng) -> None:

@@ -56,6 +56,7 @@ from repro.parallel.sharding import (
 
 from .kv_cache import BlockAllocator, SCRATCH_BLOCK, padded_prompt_len
 from .observability import (
+    HOST_PHASES,
     MetricsRegistry,
     TraceRecorder,
     macs_per_token_by_mode,
@@ -126,6 +127,10 @@ class ServeStats:
     deadline_cancelled: int = 0  # requests cancelled at deadline expiry
     resume_latency_s: List[float] = dataclasses.field(default_factory=list)
     resume_latency_steps: List[int] = dataclasses.field(default_factory=list)
+    # host seconds per step phase (observability.HOST_PHASES), summed and
+    # the longest single occurrence; filled by the continuous engine
+    host_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    host_max_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     # observability binding (engine-managed): once set, quantiles are
     # computed from the registry's step-latency histogram, whose live
     # source is this object's own step_latency_s — one source of truth
@@ -146,6 +151,11 @@ class ServeStats:
 
     def record_step(self, seconds: float) -> None:
         self.step_latency_s.append(seconds)
+
+    def record_phase(self, phase: str, seconds: float) -> None:
+        self.host_s[phase] = self.host_s.get(phase, 0.0) + seconds
+        if seconds > self.host_max_s.get(phase, 0.0):
+            self.host_max_s[phase] = seconds
 
     def latency_quantile(self, q: float) -> float:
         if self._registry is not None:
@@ -399,12 +409,13 @@ class PagedServeConfig:
     clock: Optional[object] = None
     # observability (see repro.serving.observability).  trace=True (the
     # default) records one typed TraceEvent per request lifecycle
-    # transition — host-side appends, well under 5% of a step's cost —
-    # exportable as JSON-lines / Chrome trace and the source of the
-    # per-request latency breakdown.  profile=True additionally wraps
-    # each engine phase in a jax.profiler TraceAnnotation so phases
-    # show as named spans in a profiler capture (off by default: it is
-    # only meaningful inside jax.profiler.trace()).
+    # transition (host-side appends), exportable as JSON-lines / Chrome
+    # trace and the source of the per-request latency breakdown.  Every
+    # step phase is timed on the host whatever these say;
+    # profile=True additionally opens a jax.profiler TraceAnnotation
+    # ``serve.<phase>`` per phase, so phases show as named spans in a
+    # profiler capture (off by default: it is only meaningful inside
+    # jax.profiler.trace()).
     trace: bool = True
     profile: bool = False
 
@@ -576,8 +587,8 @@ class ContinuousBatchingEngine:
         self._next_rid = 0
         self.stats = ServeStats()
         # observability: trace recorder (on by default — cheap host-side
-        # appends), metrics registry wired with live sources, opt-in
-        # profiler annotations
+        # appends), metrics registry wired with live sources, phase
+        # timing (always) with opt-in profiler annotations
         self._profile = pcfg.profile
         self.trace: Optional[TraceRecorder] = (
             TraceRecorder(
@@ -749,17 +760,20 @@ class ContinuousBatchingEngine:
                 "committed tokens per verify step per slot",
                 lambda: self.stats.tokens_per_verify_step(),
             ),
-            "serve_tok_per_s": (
-                "generated tokens over summed step wall time",
-                lambda: (
-                    self.stats.generated_tokens / t
-                    if (t := sum(self.stats.step_latency_s))
-                    else 0.0
-                ),
-            ),
         }
         for name, (help_, src) in gauges.items():
             m.gauge(name, help_).set_source(src)
+        for phase in HOST_PHASES:
+            m.counter(
+                "serve_host_seconds_total",
+                "host seconds spent in each engine step phase",
+                phase=phase,
+            ).set_source(lambda p=phase: self.stats.host_s.get(p, 0.0))
+            m.gauge(
+                "serve_host_max_seconds",
+                "longest single occurrence of each engine step phase",
+                phase=phase,
+            ).set_source(lambda p=phase: self.stats.host_max_s.get(p, 0.0))
         m.histogram(
             "serve_step_latency_seconds", "wall seconds per engine step"
         ).set_source(lambda: self.stats.step_latency_s)
@@ -788,6 +802,18 @@ class ContinuousBatchingEngine:
         """Trace hook: record one typed event (no-op when tracing off)."""
         if self.trace is not None:
             self.trace.emit(etype, rid, self._step_no, **payload)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """Time one step phase (a key of ``HOST_PHASES``) on the host
+        into ``stats.host_s`` / ``host_max_s``; under ``profile`` also
+        open the profiler span ``serve.<name>``.  Phases never nest."""
+        t0 = time.perf_counter()
+        try:
+            with phase_annotation("serve." + name, self._profile):
+                yield
+        finally:
+            self.stats.record_phase(name, time.perf_counter() - t0)
 
     def _mesh_ctx(self):
         """Context manager activating the engine's mesh (no-op at tp=1)."""
@@ -865,63 +891,69 @@ class ContinuousBatchingEngine:
         step = self._step_no
         finished: List[Request] = []
 
-        # deadline sweep: expired requests are cancelled wherever they
-        # live (waiting / running / preempted), keeping committed output
-        for req in self.scheduler.expired(self._clock()):
-            self._cancel(req, step)
-            self.stats.deadline_cancelled += 1
-            self._emit(
-                "DEADLINE",
-                req.rid,
-                deadline_s=req.deadline_s,
-                out_len=len(req.output),
-            )
-            finished.append(req)
-
-        for req in self.scheduler.admit(step, on_preempt=self._on_preempt):
-            if req.preempted_step >= 0:  # recompute-resume re-admission
-                self.stats.resumes += 1
-                self.stats.resume_latency_steps.append(step - req.preempted_step)
-                self.stats.resume_latency_s.append(
-                    self._clock() - req.preempted_time
-                )
+        with self._phase("admit"):
+            # deadline sweep: expired requests are cancelled wherever they
+            # live (waiting / running / preempted), keeping committed output
+            for req in self.scheduler.expired(self._clock()):
+                self._cancel(req, step)
+                self.stats.deadline_cancelled += 1
                 self._emit(
-                    "RESUME",
+                    "DEADLINE",
                     req.rid,
-                    slot=req.slot,
-                    blocks=len(req.alloc.blocks),
-                    parked_steps=step - req.preempted_step,
-                    cached_len=req.cached_len,
+                    deadline_s=req.deadline_s,
+                    out_len=len(req.output),
                 )
-                req.preempted_step = -1
-            else:
-                self._emit(
-                    "ADMIT",
-                    req.rid,
-                    slot=req.slot,
-                    blocks=len(req.alloc.blocks),
-                    cached_len=req.cached_len,
-                )
+                finished.append(req)
+            admitted = self.scheduler.admit(step, on_preempt=self._on_preempt)
+            for req in admitted:
+                if req.preempted_step >= 0:  # recompute-resume re-admission
+                    self.stats.resumes += 1
+                    self.stats.resume_latency_steps.append(step - req.preempted_step)
+                    self.stats.resume_latency_s.append(
+                        self._clock() - req.preempted_time
+                    )
+                    self._emit(
+                        "RESUME",
+                        req.rid,
+                        slot=req.slot,
+                        blocks=len(req.alloc.blocks),
+                        parked_steps=step - req.preempted_step,
+                        cached_len=req.cached_len,
+                    )
+                    req.preempted_step = -1
+                else:
+                    self._emit(
+                        "ADMIT",
+                        req.rid,
+                        slot=req.slot,
+                        blocks=len(req.alloc.blocks),
+                        cached_len=req.cached_len,
+                    )
             if self.pcfg.prefill_chunk:
                 # blocks + slot reserved; the prompt is fed chunkwise
                 # (the slot stays scratch-masked until prefill is done)
-                self._prefilling.append(req)
-            else:
-                self._do_prefill(req)
-                if req.is_done():  # max_new_tokens == 1: done at prefill
+                self._prefilling.extend(admitted)
+                admitted = []
+
+        for req in admitted:
+            self._do_prefill(req)
+            if req.is_done():  # max_new_tokens == 1: done at prefill
+                with self._phase("sample"):
                     self._release(req, step)
-                    finished.append(req)
+                finished.append(req)
 
         if self._prefilling:
             req = self._prefilling[0]
             if self._do_prefill_chunk(req):
                 self._prefilling.popleft()
                 if req.is_done():  # max_new_tokens == 1 / stop at first token
-                    self._release(req, step)
+                    with self._phase("sample"):
+                        self._release(req, step)
                     finished.append(req)
 
         if self.pcfg.preemption == "recompute":
-            self._grow_active(step)
+            with self._phase("admit"):
+                self._grow_active(step)
 
         if any(r.prefill_done for r in self.scheduler.running.values()):
             if self.pcfg.spec_k:
@@ -1001,37 +1033,27 @@ class ContinuousBatchingEngine:
             self._resume_via_chunk(req)
             return
         self._flush_scrubs()
-        bs = self.pcfg.block_size
-        plen = req.prefill_len
-        s_pad = padded_prompt_len(plen, bs)
-        toks = np.zeros((1, s_pad), np.int32)
-        toks[0, :plen] = req.prefill_tokens
-        block_ids = jnp.asarray(req.alloc.blocks[: s_pad // bs], jnp.int32)
-        with self._mesh_ctx(), phase_annotation("serve.prefill", self._profile):
+        with self._phase("prefill"), self._mesh_ctx():
+            bs = self.pcfg.block_size
+            plen = req.prefill_len
+            s_pad = padded_prompt_len(plen, bs)
+            toks = np.zeros((1, s_pad), np.int32)
+            toks[0, :plen] = req.prefill_tokens
             logits, (self._k_pool, self._v_pool) = self._prefill(
                 self.params,
                 jnp.asarray(toks),
                 self._k_pool,
                 self._v_pool,
-                block_ids,
+                jnp.asarray(req.alloc.blocks[: s_pad // bs], jnp.int32),
                 jnp.int32(plen),
             )
-        req.prefill_pos = plen
-        req.verified_len = plen
-        req.drafted_len = s_pad  # pad positions hold junk K/V until overwritten
-        self._finish_prefill(req, logits[0, -1])
-        self.stats.prefills += 1
-        self.stats.prefill_tokens += plen
-        self.stats.prefill_padding += s_pad - plen
-        self._emit(
-            "PREFILL_CHUNK",
-            req.rid,
-            start=0,
-            tokens=plen,
-            width=s_pad,
-            done=True,
-            out_len=len(req.output),
-        )
+            req.prefill_pos = plen
+            req.verified_len = plen
+            req.drafted_len = s_pad  # pad positions hold junk K/V until overwritten
+            self.stats.prefills += 1
+            self.stats.prefill_tokens += plen
+            self.stats.prefill_padding += s_pad - plen
+        self._finish_prefill(req, logits, start=0, tokens=plen, width=s_pad)
 
     def _resume_via_chunk(self, req: Request) -> None:
         """Recompute-resume: rewrite the K/V of the committed context
@@ -1051,67 +1073,68 @@ class ContinuousBatchingEngine:
         if req.cow_src is not None:
             self._apply_cow(req)
         self._flush_scrubs()
-        bs = self.pcfg.block_size
-        plen = req.prefill_len
-        start = req.prefill_pos
-        remaining = plen - start
-        width = padded_prompt_len(remaining, bs)
-        toks = np.zeros((1, width), np.int32)
-        toks[0, :remaining] = req.prefill_tokens[start:]
-        table_row = jnp.asarray(
-            req.alloc.table_row(self.max_blocks_per_seq), jnp.int32
-        )
-        with self._mesh_ctx(), phase_annotation("serve.prefill", self._profile):
+        with self._phase("prefill"), self._mesh_ctx():
+            plen = req.prefill_len
+            start = req.prefill_pos
+            remaining = plen - start
+            width = padded_prompt_len(remaining, self.pcfg.block_size)
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :remaining] = req.prefill_tokens[start:]
             logits, (self._k_pool, self._v_pool) = self._prefill_chunk(
                 self.params,
                 jnp.asarray(toks),
                 self._k_pool,
                 self._v_pool,
-                table_row,
+                jnp.asarray(req.alloc.table_row(self.max_blocks_per_seq), jnp.int32),
                 jnp.int32(start),
                 jnp.int32(remaining - 1),
             )
-        req.prefill_pos = plen
-        req.verified_len = plen
-        # suffix padding past capacity lands on the scratch block via
-        # the padded table row; only in-capacity positions can be dirty
-        req.drafted_len = max(
-            req.drafted_len, min(start + width, req.alloc.capacity())
-        )
-        self._finish_prefill(req, logits[0, -1])
-        self.stats.prefills += 1
-        self.stats.prefill_tokens += remaining
-        self.stats.prefill_padding += width - remaining
-        self._emit(
-            "PREFILL_CHUNK",
-            req.rid,
-            start=start,
-            tokens=remaining,
-            width=width,
-            done=True,
-            out_len=len(req.output),
-        )
+            req.prefill_pos = plen
+            req.verified_len = plen
+            # suffix padding past capacity lands on the scratch block via
+            # the padded table row; only in-capacity positions can be dirty
+            req.drafted_len = max(
+                req.drafted_len, min(start + width, req.alloc.capacity())
+            )
+            self.stats.prefills += 1
+            self.stats.prefill_tokens += remaining
+            self.stats.prefill_padding += width - remaining
+        self._finish_prefill(req, logits, start=start, tokens=remaining, width=width)
 
-    def _finish_prefill(self, req: Request, last_logits) -> None:
-        """Activate a fully-prefilled slot.  Fresh requests sample
-        their first token from the prefill logits; a resumed request
-        already committed that continuation — its last output token is
-        re-fed as the next decode input instead (sampling again would
-        double-emit it)."""
-        if req.output:
-            tok = req.output[-1]
-        else:
-            tok = int(self._pick_one(last_logits, req, len(req.output)))
-            req.output.append(tok)
-            self.stats.generated_tokens += 1
-        slot = req.slot
-        self._tables[slot] = req.alloc.table_row(self.max_blocks_per_seq)
-        self._lengths[slot] = req.prefill_len
-        self._last_tok[slot] = tok
-        if self.allocator.prefix_cache:
-            # publish only now that the K/V is really in the pool — a
-            # hash->block mapping must never race ahead of pool content
-            self.allocator.register(req.prefill_tokens, req.alloc.blocks)
+    def _finish_prefill(
+        self, req: Request, logits, start: int, tokens: int, width: int
+    ) -> None:
+        """Activate a fully-prefilled slot and trace its last prefill
+        call (``start``, real ``tokens``, padded ``width``).  Fresh
+        requests sample their first token from the last position of the
+        prefill ``logits``; a resumed request already committed that
+        continuation — its last output token is re-fed as the next
+        decode input instead (sampling again would double-emit it)."""
+        last = None
+        if not req.output:
+            with self._phase("readback"):
+                last = np.asarray(logits[0, -1])
+        with self._phase("sample"):
+            if last is not None:
+                req.output.append(int(self._pick_one(last, req, 0)))
+                self.stats.generated_tokens += 1
+            slot = req.slot
+            self._tables[slot] = req.alloc.table_row(self.max_blocks_per_seq)
+            self._lengths[slot] = req.prefill_len
+            self._last_tok[slot] = req.output[-1]
+            if self.allocator.prefix_cache:
+                # publish only now that the K/V is really in the pool — a
+                # hash->block mapping must never race ahead of pool content
+                self.allocator.register(req.prefill_tokens, req.alloc.blocks)
+            self._emit(
+                "PREFILL_CHUNK",
+                req.rid,
+                start=start,
+                tokens=tokens,
+                width=width,
+                done=True,
+                out_len=len(req.output),
+            )
 
     def _do_prefill_chunk(self, req: Request) -> bool:
         """Write ONE chunk of `req`'s prompt into its pool blocks.
@@ -1126,94 +1149,83 @@ class ContinuousBatchingEngine:
         if req.cow_src is not None:
             self._apply_cow(req)
         self._flush_scrubs()
-        bs, chunk = self.pcfg.block_size, self.pcfg.prefill_chunk
-        start = req.prefill_pos
-        remaining = req.prefill_len - start
-        width = chunk if remaining > chunk else padded_prompt_len(remaining, bs)
-        real = min(remaining, chunk)
-        toks = np.zeros((1, width), np.int32)
-        toks[0, :real] = req.prefill_tokens[start : start + real]
-        table_row = jnp.asarray(
-            req.alloc.table_row(self.max_blocks_per_seq), jnp.int32
-        )
-        with self._mesh_ctx(), phase_annotation("serve.prefill", self._profile):
+        with self._phase("prefill"), self._mesh_ctx():
+            bs, chunk = self.pcfg.block_size, self.pcfg.prefill_chunk
+            start = req.prefill_pos
+            remaining = req.prefill_len - start
+            width = chunk if remaining > chunk else padded_prompt_len(remaining, bs)
+            real = min(remaining, chunk)
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :real] = req.prefill_tokens[start : start + real]
             logits, (self._k_pool, self._v_pool) = self._prefill_chunk(
                 self.params,
                 jnp.asarray(toks),
                 self._k_pool,
                 self._v_pool,
-                table_row,
+                jnp.asarray(req.alloc.table_row(self.max_blocks_per_seq), jnp.int32),
                 jnp.int32(start),
                 jnp.int32(real - 1),
             )
-        req.prefill_pos = start + real
-        req.verified_len = start + real
-        # chunk padding past capacity is absorbed by the scratch block
-        # (padded table row) — only in-capacity positions can be dirty
-        req.drafted_len = max(
-            req.drafted_len, min(start + width, req.alloc.capacity())
-        )
-        self.stats.prefills += 1
-        self.stats.prefill_tokens += real
-        self.stats.prefill_padding += width - real
-        if not req.prefill_done:
-            self._emit(
-                "PREFILL_CHUNK",
-                req.rid,
-                start=start,
-                tokens=real,
-                width=width,
-                done=False,
-                out_len=len(req.output),
+            req.prefill_pos = start + real
+            req.verified_len = start + real
+            # chunk padding past capacity is absorbed by the scratch block
+            # (padded table row) — only in-capacity positions can be dirty
+            req.drafted_len = max(
+                req.drafted_len, min(start + width, req.alloc.capacity())
             )
-            return False
-        self._finish_prefill(req, logits[0, -1])
-        self._emit(
-            "PREFILL_CHUNK",
-            req.rid,
-            start=start,
-            tokens=real,
-            width=width,
-            done=True,
-            out_len=len(req.output),
-        )
+            self.stats.prefills += 1
+            self.stats.prefill_tokens += real
+            self.stats.prefill_padding += width - real
+            if not req.prefill_done:
+                self._emit(
+                    "PREFILL_CHUNK",
+                    req.rid,
+                    start=start,
+                    tokens=real,
+                    width=width,
+                    done=False,
+                    out_len=len(req.output),
+                )
+                return False
+        self._finish_prefill(req, logits, start=start, tokens=real, width=width)
         return True
 
     def _do_decode(self, step: int) -> List[Request]:
         self._flush_scrubs()
-        token = jnp.asarray(self._last_tok[:, None])
-        with self._mesh_ctx(), phase_annotation("serve.decode", self._profile):
+        with self._phase("decode"), self._mesh_ctx():
             logits, (self._k_pool, self._v_pool) = self._decode(
                 self.params,
-                token,
+                jnp.asarray(self._last_tok[:, None]),
                 self._k_pool,
                 self._v_pool,
                 jnp.asarray(self._tables),
                 jnp.asarray(self._lengths),
             )
-        logits = np.asarray(logits[:, 0], np.float32)
+        with self._phase("readback"):
+            logits = np.asarray(logits[:, 0], np.float32)
 
-        finished = []
-        active = [
-            (slot, req)
-            for slot, req in self.scheduler.running.items()
-            if req.prefill_done
-        ]
-        self.stats.decode_steps += 1
-        self.stats.active_slot_steps += len(active)
-        self.stats.idle_slot_steps += self.pcfg.max_slots - len(active)
-        for slot, req in active:
-            tok = int(self._pick_one(logits[slot], req, len(req.output)))
-            req.output.append(tok)
-            self._lengths[slot] += 1
-            req.verified_len = int(self._lengths[slot])
-            req.drafted_len = max(req.drafted_len, req.verified_len)
-            self._last_tok[slot] = tok
-            self.stats.generated_tokens += 1
-            self._emit("DECODE", req.rid, new_tokens=1, out_len=len(req.output))
-            if req.is_done():
-                self._release(req, step)
-                finished.append(req)
+        with self._phase("sample"):
+            finished = []
+            active = [
+                (slot, req)
+                for slot, req in self.scheduler.running.items()
+                if req.prefill_done
+            ]
+            self.stats.decode_steps += 1
+            self.stats.active_slot_steps += len(active)
+            self.stats.idle_slot_steps += self.pcfg.max_slots - len(active)
+            for slot, req in active:
+                tok = int(self._pick_one(logits[slot], req, len(req.output)))
+                req.output.append(tok)
+                self._lengths[slot] += 1
+                req.verified_len = int(self._lengths[slot])
+                req.drafted_len = max(req.drafted_len, req.verified_len)
+                self._last_tok[slot] = tok
+                self.stats.generated_tokens += 1
+                self._emit("DECODE", req.rid, new_tokens=1, out_len=len(req.output))
+                if req.is_done():
+                    self._release(req, step)
+                    finished.append(req)
         return finished
 
     def _do_verify(self, step: int) -> List[Request]:
@@ -1233,23 +1245,23 @@ class ContinuousBatchingEngine:
         k = self.pcfg.spec_k
         w = k + 1
         m = self.pcfg.max_slots
-        active = [
-            (slot, req)
-            for slot, req in self.scheduler.running.items()
-            if req.prefill_done
-        ]
-        tokens = np.zeros((m, w), np.int32)
-        tokens[:, 0] = self._last_tok
-        drafts: Dict[int, List[int]] = {}
-        propose_hist = self.metrics.histogram("serve_draft_propose_seconds")
-        for slot, req in active:
-            td = time.perf_counter()
-            d = self.drafter.propose(req, k)
-            propose_hist.observe(time.perf_counter() - td)
-            assert len(d) == k, (len(d), k)
-            drafts[slot] = d
-            tokens[slot, 1:] = d
-        with self._mesh_ctx(), phase_annotation("serve.verify", self._profile):
+        with self._phase("verify"), self._mesh_ctx():
+            active = [
+                (slot, req)
+                for slot, req in self.scheduler.running.items()
+                if req.prefill_done
+            ]
+            tokens = np.zeros((m, w), np.int32)
+            tokens[:, 0] = self._last_tok
+            drafts: Dict[int, List[int]] = {}
+            propose_hist = self.metrics.histogram("serve_draft_propose_seconds")
+            for slot, req in active:
+                td = time.perf_counter()
+                d = self.drafter.propose(req, k)
+                propose_hist.observe(time.perf_counter() - td)
+                assert len(d) == k, (len(d), k)
+                drafts[slot] = d
+                tokens[slot, 1:] = d
             logits, (self._k_pool, self._v_pool) = self._score(
                 self.params,
                 jnp.asarray(tokens),
@@ -1258,45 +1270,47 @@ class ContinuousBatchingEngine:
                 jnp.asarray(self._tables),
                 jnp.asarray(self._lengths),
             )
-        logits = np.asarray(logits, np.float32)  # [m, w, V]
+        with self._phase("readback"):
+            logits = np.asarray(logits, np.float32)  # [m, w, V]
 
-        finished = []
-        self.stats.decode_steps += 1
-        self.stats.spec_steps += 1
-        self.stats.active_slot_steps += len(active)
-        self.stats.idle_slot_steps += m - len(active)
-        for slot, req in active:
-            base = int(self._lengths[slot])
-            req.drafted_len = max(req.drafted_len, base + w)
-            targets = np.argmax(logits[slot], axis=-1)
-            d = drafts[slot]
-            a = 0
-            while a < k and d[a] == int(targets[a]):
-                a += 1
-            self.stats.drafted_tokens += k
-            self.stats.accepted_tokens += a
-            committed = 0
-            for t in targets[: a + 1]:
-                req.output.append(int(t))
-                committed += 1
-                self.stats.generated_tokens += 1
-                self.stats.spec_committed_tokens += 1
-                if req.is_done():  # stop_token / max_new hit mid-burst
-                    break
-            self._lengths[slot] = base + committed
-            self._last_tok[slot] = req.output[-1]
-            self.scheduler.rollback(req, base + committed)
-            self._emit(
-                "VERIFY",
-                req.rid,
-                k=k,
-                accepted=a,
-                new_tokens=committed,
-                out_len=len(req.output),
-            )
-            if req.is_done():
-                self._release(req, step)
-                finished.append(req)
+        with self._phase("sample"):
+            finished = []
+            self.stats.decode_steps += 1
+            self.stats.spec_steps += 1
+            self.stats.active_slot_steps += len(active)
+            self.stats.idle_slot_steps += m - len(active)
+            for slot, req in active:
+                base = int(self._lengths[slot])
+                req.drafted_len = max(req.drafted_len, base + w)
+                targets = np.argmax(logits[slot], axis=-1)
+                d = drafts[slot]
+                a = 0
+                while a < k and d[a] == int(targets[a]):
+                    a += 1
+                self.stats.drafted_tokens += k
+                self.stats.accepted_tokens += a
+                committed = 0
+                for t in targets[: a + 1]:
+                    req.output.append(int(t))
+                    committed += 1
+                    self.stats.generated_tokens += 1
+                    self.stats.spec_committed_tokens += 1
+                    if req.is_done():  # stop_token / max_new hit mid-burst
+                        break
+                self._lengths[slot] = base + committed
+                self._last_tok[slot] = req.output[-1]
+                self.scheduler.rollback(req, base + committed)
+                self._emit(
+                    "VERIFY",
+                    req.rid,
+                    k=k,
+                    accepted=a,
+                    new_tokens=committed,
+                    out_len=len(req.output),
+                )
+                if req.is_done():
+                    self._release(req, step)
+                    finished.append(req)
         return finished
 
     def _grow_active(self, step: int) -> None:
@@ -1404,12 +1418,12 @@ class ContinuousBatchingEngine:
         self._scrub_pending.extend(self.allocator.drain_evicted())
         if not self._scrub_pending:
             return
-        blocks, self._scrub_pending = self._scrub_pending, []
-        w = self.max_blocks_per_seq
-        n = -(-len(blocks) // w) * w
-        ids = np.full((n,), SCRATCH_BLOCK, np.int32)
-        ids[: len(blocks)] = blocks
-        with self._mesh_ctx(), phase_annotation("serve.scrub", self._profile):
+        with self._phase("scrub"), self._mesh_ctx():
+            blocks, self._scrub_pending = self._scrub_pending, []
+            w = self.max_blocks_per_seq
+            n = -(-len(blocks) // w) * w
+            ids = np.full((n,), SCRATCH_BLOCK, np.int32)
+            ids[: len(blocks)] = blocks
             self._k_pool, self._v_pool = self._scrub_fn(
                 self._k_pool, self._v_pool, jnp.asarray(ids)
             )
@@ -1424,20 +1438,20 @@ class ContinuousBatchingEngine:
         src = req.cow_src
         assert src is not None
         self._flush_scrubs()
-        dst = req.alloc.blocks[req.cached_len // self.pcfg.block_size]
-        with self._mesh_ctx(), phase_annotation("serve.cow", self._profile):
+        with self._phase("cow"), self._mesh_ctx():
+            dst = req.alloc.blocks[req.cached_len // self.pcfg.block_size]
             self._k_pool, self._v_pool = self._cow_fn(
                 self._k_pool, self._v_pool, jnp.int32(src), jnp.int32(dst)
             )
-        req.cow_src = None
-        self._scrub(self.allocator.release([src]))
+            req.cow_src = None
+            self._scrub(self.allocator.release([src]))
 
     def _pick_one(self, logits_row, req: Request, token_idx: int):
         if self.pcfg.temperature <= 0:
-            # host-side argmax: logits are already materialized as numpy
-            # in the decode loop; a jnp.argmax here would re-upload every
+            # host-side argmax: callers materialize logits as numpy (the
+            # readback phase); a jnp.argmax here would re-upload every
             # row and add a device round-trip per slot per step
-            return int(np.argmax(np.asarray(logits_row)))
+            return int(np.argmax(logits_row))
         key = jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(self.pcfg.seed), req.rid),
             token_idx,

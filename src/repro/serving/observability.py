@@ -1,4 +1,4 @@
-"""Serving observability: structured tracing, metrics, profiling hooks.
+"""Serving observability: structured tracing, metrics, phase timing.
 
 Three cooperating pieces, all host-side and dependency-light (numpy
 only; jax is imported lazily and only for the opt-in profiler
@@ -30,10 +30,15 @@ annotations):
   ``repro.core.policy``), which makes metric collection free on the
   hot path and immune to benchmark-style stats resets.
 
-* :func:`phase_annotation` — an opt-in ``jax.profiler``
-  TraceAnnotation context per engine phase, a no-op unless profiling
-  is enabled, so engine phases show up as named spans in a jax
-  profiler trace.
+* Host phase timing — the engine times every phase of its step
+  (:data:`HOST_PHASES`: admission, prefill and decode/verify dispatch,
+  logits readback, sampling, scrub, copy-on-write) on the host clock,
+  always, into ``ServeStats.host_s`` / ``host_max_s``, exported as
+  ``serve_host_seconds_total{phase=...}`` and
+  ``serve_host_max_seconds{phase=...}``.  Only the profiler span is
+  opt-in: with profiling enabled, :func:`phase_annotation` also opens a
+  ``jax.profiler`` TraceAnnotation ``serve.<phase>``, so each phase is a
+  named span on the device trace's clock.
 
 Run ``python -m repro.serving.observability trace.jsonl [--prom
 metrics.prom]`` to schema-check an exported trace (CI does, on the
@@ -75,6 +80,13 @@ EVENT_TYPES: Tuple[str, ...] = tuple(EVENT_SCHEMA)
 
 #: Exactly one of these ends every request's event sequence.
 TERMINAL_EVENTS: Tuple[str, ...] = ("FINISH", "CANCEL", "DEADLINE")
+
+#: Engine step phases timed on the host (``ServeStats.host_s``); with
+#: profiling on, each is also the span ``serve.<phase>``.  Phases never
+#: nest, so their seconds add up to at most the step's wall time.
+HOST_PHASES: Tuple[str, ...] = (
+    "admit", "prefill", "decode", "verify", "readback", "sample", "scrub", "cow",
+)
 
 #: Occupancy keys the recorder stamps onto every event.
 _OCCUPANCY_KEYS = ("free_blocks", "used_blocks")
@@ -694,14 +706,17 @@ def macs_per_token_by_mode(cfg) -> Dict[str, float]:
 
 
 # ---------------------------------------------------------------------------
-# profiling hooks
+# profiler spans
 # ---------------------------------------------------------------------------
 
 
 def phase_annotation(name: str, enabled: bool = True):
-    """Context manager annotating an engine phase in the jax profiler
-    timeline.  A no-op (null context) when disabled or when
-    jax.profiler is unavailable, so the hot path never pays for it."""
+    """Context manager opening the jax profiler span ``name``.
+
+    The engine's phase timer calls it inside every timed phase; the
+    phase's host seconds are counted whether or not it is enabled, and
+    only the span is opt-in.  A null context when disabled or when
+    jax.profiler is unavailable."""
     if not enabled:
         import contextlib
 
