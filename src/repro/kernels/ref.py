@@ -35,7 +35,7 @@ def plam_matmul_seqref(a_bits, b_bits, spec: PositSpec):
     ``plam_matmul_ref`` reduces with ``jnp.sum``, whose f32 reduction
     order XLA does not pin down, so it is only allclose to the kernel.
     This reference accumulates k strictly ascending — the order the
-    kernel's ``fori_loop`` walks lanes within and across K blocks — so
+    kernel's k loop walks lanes within and across K blocks — so
     ``np.array_equal`` comparisons are valid for any (M, N, K), ragged
     or not.  The kernel's zero-padding lanes add exactly +0.0 and both
     accumulators start at +0.0, so padding never perturbs a bit.

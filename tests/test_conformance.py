@@ -108,6 +108,11 @@ RAGGED_SHAPES = [
     (9, 257, 5),    # K spans three blocks with a ragged tail
     (2, 1, 2),      # K = 1
     (17, 64, 33),   # ragged M and N
+    (8, 256, 1536),  # decode M: three 512-wide N tiles
+    (8, 384, 1280),  # decode M: five 256-wide N tiles
+    (8, 256, 1100),  # decode M, ragged N: 128-wide tiles, N padded
+    (16, 130, 640),  # prefill M, K just past one block
+    (64, 384, 1024),  # prefill M: 128-wide tiles
 ]
 
 
@@ -126,6 +131,34 @@ def test_plam_matmul_bit_identical_to_seqref(shape):
     got = np.asarray(plam_matmul_bits(a, b, spec, interpret=True))
     assert np.array_equal(want.view(np.uint32), got.view(np.uint32)), (
         f"shape {shape}: kernel diverged from sequential reference")
+
+
+def _extreme_patterns(spec):
+    """maxpos, minpos, their negatives, +-1, zero and NaR."""
+    one = 1 << (spec.n - 2)
+    pos = [spec.maxpos_body, 1, one]
+    return pos + [(-p) & spec.mask_n for p in pos] + [0, spec.nar]
+
+
+@pytest.mark.parametrize("k", [1, 16], ids=["single", "summed"])
+@pytest.mark.parametrize(
+    "spec", [PositSpec(16, 1), PositSpec(8, 0), PositSpec(16, 2)], ids=str)
+def test_plam_matmul_extremes_bit_identical_to_seqref(spec, k):
+    """The sign folded into the log words at the scale extremes: every
+    pair of maxpos, minpos, their negatives, +-1, zero and NaR, alone
+    (k=1) and summed, across a 512-wide decode tile."""
+    pats = np.array(_extreme_patterns(spec), np.int32)
+    p = len(pats)
+    m, n = 8, 512
+    a = pats[(np.arange(m)[:, None] + np.arange(k)[None, :]) % p]
+    b = pats[(3 * np.arange(k)[:, None] + np.arange(n)[None, :]) % p]
+    want = np.asarray(plam_matmul_seqref(a, b, spec))
+    got = np.asarray(plam_matmul_bits(a, b, spec, interpret=True))
+    assert np.array_equal(want.view(np.uint32), got.view(np.uint32))
+    if k == 1:  # a lone product of a zero or NaR is exactly +0.0
+        live = ~np.isin(a[:, :1], [0, spec.nar]) & ~np.isin(b[:1, :], [0, spec.nar])
+        assert np.all(got.view(np.uint32)[~live] == 0)
+        assert np.all(got[live] != 0)
 
 
 def test_plam_matmul_seqref_close_to_sum_ref():

@@ -12,6 +12,7 @@ from repro.kernels import (
     posit_encode,
     posit_quantize,
 )
+from repro.kernels.plam_matmul import tile_sizes
 from repro.kernels.ref import plam_dense_ref, plam_matmul_ref, posit_quantize_ref
 
 SPECS = [PositSpec(16, 1), PositSpec(8, 0), PositSpec(16, 2)]
@@ -54,6 +55,36 @@ def test_plam_matmul_zero_and_sign_handling():
     ref = np.asarray(plam_matmul_ref(a, b, spec))
     ker = np.asarray(plam_matmul_bits(a, b, spec, bm=8, bn=8, bk=8, interpret=True))
     np.testing.assert_allclose(ker, ref, rtol=1e-6, atol=1e-6)
+
+
+# Yi-6B's (K, N) weight shapes whole, then as the column slices each
+# device multiplies at tp=4, with the decode N tile each should take.
+YI_WEIGHTS = [
+    ((4096, 4096), 512), ((4096, 512), 512), ((4096, 11008), 256),
+    ((11008, 4096), 512), ((4096, 64000), 512),
+    ((4096, 1024), 512), ((4096, 128), 128), ((4096, 2752), 128),
+    ((11008, 1024), 512), ((4096, 16000), 128),
+]
+
+
+@pytest.mark.parametrize("kn,decode_bn", YI_WEIGHTS, ids=str)
+def test_tile_sizes_never_pad_a_weight(kn, decode_bn):
+    """At decode M the N tile widens to the widest that divides N, so a
+    weight whose N is a multiple of 128 is never padded; from M = 16 up
+    the tiles stay (min(M, 128), 128, 128)."""
+    k, n = kn
+    for m in (1, 8):
+        bm, bn, bk = tile_sizes(m, k, n)
+        assert (bm, bn, bk) == (m, decode_bn, 128)
+        assert n % 128 or n % bn == 0
+    for m in (16, 64, 256):
+        assert tile_sizes(m, k, n) == (min(m, 128), 128, 128)
+
+
+@pytest.mark.parametrize(
+    "n,bn", [(1536, 512), (1152, 384), (1280, 256), (640, 128), (1100, 128), (100, 100)])
+def test_tile_sizes_take_the_widest_dividing_tile(n, bn):
+    assert tile_sizes(8, 256, n)[1] == bn
 
 
 def test_plam_dense_batched():

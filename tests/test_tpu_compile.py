@@ -49,12 +49,18 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-@pytest.mark.parametrize("m,k,n", [(8, 4096, 11008), (128, 4096, 4096)])
+@pytest.mark.parametrize(
+    "m,k,n",
+    [(8, 4096, 11008), (128, 4096, 4096), (64, 4096, 11008), (8, 4096, 64000),
+     (8, 4096, 2752)],
+)
 def test_plam_matmul_compiles(one_chip, m, k, n):
     a = jax.ShapeDtypeStruct((m, k), jnp.int32, sharding=one_chip)
     b = jax.ShapeDtypeStruct((k, n), jnp.int32, sharding=one_chip)
     text = _compiled_text(lambda x, y: plam_matmul(x, y, SPEC), a, b)
-    assert "tpu_custom_call" in text
+    assert "%plam_matmul" in text and "tpu_custom_call" in text
+    if n % 128 == 0:  # the weight reaches the kernel as it is, unpadded
+        assert " pad(" not in text
 
 
 @pytest.mark.parametrize(
