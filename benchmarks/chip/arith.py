@@ -2,7 +2,9 @@
 
 The yardstick's own arithmetic: what a call has to compute and move,
 whatever implements it.  ``model`` is a configuration file's ``model``
-section (Hugging Face key names).
+section (Hugging Face key names); ``sites`` is the (K, N) of the
+matmuls one token passes through in one layer, as the configuration's
+model family gives them (``layer_sites(model)`` in ``families/``).
 """
 from __future__ import annotations
 
@@ -25,17 +27,9 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def layer_sites(model: dict):
-    """(K, N) of every matmul in one decoder layer."""
-    d, ff = model["hidden_size"], model["intermediate_size"]
-    q = model["num_attention_heads"] * model["head_dim"]
-    kv = model["num_key_value_heads"] * model["head_dim"]
-    return [(d, q), (d, kv), (d, kv), (q, d), (d, ff), (d, ff), (ff, d)]
-
-
-def layer_macs(model: dict) -> int:
+def layer_macs(sites, model: dict) -> int:
     """Multiply-adds of one token through the layers' matmuls."""
-    return model["num_hidden_layers"] * sum(k * n for k, n in layer_sites(model))
+    return model["num_hidden_layers"] * sum(k * n for k, n in sites)
 
 
 def head_macs(model: dict) -> int:
@@ -48,10 +42,10 @@ def attention_flops(model: dict, keys: int) -> int:
     return 4 * model["num_hidden_layers"] * keys * h * hd
 
 
-def token_flops(model: dict, keys: int, logits: bool) -> int:
+def token_flops(sites, model: dict, keys: int, logits: bool) -> int:
     """Model FLOPs of one token: 2 per matmul MAC plus attention; the
     output head only where the token's logits are used."""
-    macs = layer_macs(model) + (head_macs(model) if logits else 0)
+    macs = layer_macs(sites, model) + (head_macs(model) if logits else 0)
     return 2 * macs + attention_flops(model, keys)
 
 
@@ -64,10 +58,11 @@ def matmul_least_s(m: int, k: int, n: int, pk: dict) -> float:
     return max(flops / pk["bf16_flops_per_s"], nbytes / pk["hbm_bytes_per_s"])
 
 
-def program_matmul_least_s(model: dict, rows: int, head_rows: int, pk: dict) -> float:
+def program_matmul_least_s(sites, model: dict, rows: int, head_rows: int,
+                           pk: dict) -> float:
     """Least time of every matmul of one model call: ``rows`` through
     each layer and ``head_rows`` through the output head."""
-    per_layer = sum(matmul_least_s(rows, k, n, pk) for k, n in layer_sites(model))
+    per_layer = sum(matmul_least_s(rows, k, n, pk) for k, n in sites)
     head = matmul_least_s(head_rows, model["hidden_size"], model["vocab_size"], pk)
     return model["num_hidden_layers"] * per_layer + head
 

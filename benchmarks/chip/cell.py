@@ -2,8 +2,11 @@
 
 Everything a cell needs is found by name: the workload entry names a
 configuration (``configs/<config>.json``) and a traffic mix
-(``traffic/<mix>.json``); each per-layer metric is
-``metrics/<metric>.py``.  A new cell needs only new files and entries.
+(``traffic/<mix>.json``); the configuration may name its model family
+(``"family"``, ``dense`` where it names none), which is
+``families/<family>.py``; each per-layer metric is
+``metrics/<metric>.py``.  A new cell, and a new architecture, need only
+new files and entries.
 """
 from __future__ import annotations
 
@@ -11,9 +14,11 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import types
 
 CHIP = pathlib.Path(__file__).resolve().parent
 ROOT = CHIP.parents[1]
+FAMILIES = CHIP / "families"
 
 
 @dataclasses.dataclass
@@ -24,6 +29,7 @@ class Cell:
     mix_path: pathlib.Path
     end_to_end: list  # BENCHMARK.json metric entries reported with --trace 0
     per_layer: list  # ... and with --trace 1
+    family: types.ModuleType  # the configuration's model family (families/<family>.py)
 
 
 def _applies(metric: dict, workload: str) -> bool:
@@ -43,22 +49,37 @@ def load_cell(workload: str, bench_path=ROOT / "BENCHMARK.json") -> Cell:
         names = [w["name"] for w in bench["workloads"]]
         raise SystemExit(f"unknown workload {workload!r}; known: {names}")
     conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    config = load_config(ROOT / conf["file"])
     return Cell(
         name=workload,
         chips=int(entry["chips"]),
-        config=load_config(ROOT / conf["file"]),
+        config=config,
         mix_path=CHIP / "traffic" / f"{entry['traffic']}.json",
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
+        family=load_family(config.get("family", "dense")),
     )
 
 
-def load_metric(name: str):
-    """``metrics/<name>.py``: its ``read(ctx)`` returns a number or None."""
-    path = CHIP / "metrics" / f"{name}.py"
+def _load_module(prefix: str, path: pathlib.Path):
     spec = importlib.util.spec_from_file_location(
-        "chip_metric_" + name.replace(".", "_").replace("-", "_"), path
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(name: str, directory: pathlib.Path = FAMILIES):
+    """``<directory>/<name>.py``: ``model_config(conf)``,
+    ``make_params(model, seed, dtype)``, ``served_logits(conf, seed,
+    seqs, controls)`` and ``layer_sites(model)``."""
+    path = pathlib.Path(directory) / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"model family {name!r}: no file {path}")
+    return _load_module("chip_family_", path)
+
+
+def load_metric(name: str):
+    """``metrics/<name>.py``: its ``read(ctx)`` returns a number or None."""
+    return _load_module("chip_metric_", CHIP / "metrics" / f"{name}.py")

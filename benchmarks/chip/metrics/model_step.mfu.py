@@ -9,19 +9,19 @@ the last prompt token).
 import arith
 
 
-def window_flops(model, steps):
+def window_flops(sites, model, steps):
     total = 0
     for s in steps:
         if s.chunk:
             start, real, plen = s.chunk
             for p in range(start, start + real):
-                total += arith.token_flops(model, p + 1, logits=(p == plen - 1))
+                total += arith.token_flops(sites, model, p + 1, logits=(p == plen - 1))
         for keys in s.decode:
-            total += arith.token_flops(model, keys, logits=True)
+            total += arith.token_flops(sites, model, keys, logits=True)
     return total
 
 
 def read(ctx):
     win = ctx["window"]
-    flops = window_flops(ctx["config"]["model"], win.steps)
+    flops = window_flops(ctx["sites"], ctx["config"]["model"], win.steps)
     return 100.0 * flops / win.seconds / ctx["peaks"]["bf16_flops_per_s"]

@@ -16,14 +16,14 @@ def read(ctx):
     kernel_s = ctx["trace"].kernel_s(KERNELS)
     if kernel_s <= 0:
         return None
-    conf, pk = ctx["config"], ctx["peaks"]
+    conf, pk, sites = ctx["config"], ctx["peaks"], ctx["sites"]
     model, block = conf["model"], conf["engine"]["block_size"]
     slots = conf["engine"]["max_slots"]
     least = 0.0
     for s in ctx["window"].steps:
         if s.chunk:
             width = -(-s.chunk[1] // block) * block
-            least += arith.program_matmul_least_s(model, width, 1, pk)
+            least += arith.program_matmul_least_s(sites, model, width, 1, pk)
         if s.decode:
-            least += arith.program_matmul_least_s(model, slots, slots, pk)
+            least += arith.program_matmul_least_s(sites, model, slots, slots, pk)
     return 100.0 * least / kernel_s

@@ -1,10 +1,10 @@
-"""Plain float32 reference of a served dense decoder, and its control.
+"""Plain float32 reference of a served decoder, and its control.
 
 This file imports nothing of the program under test.  From a
 configuration file and a seed it remakes the weights one layer at a
-time (``weights.py``), and runs the causal LM over whole sequences
-(prompt plus served tokens) in float32, with every matmul of the
-configuration's numerics:
+time (``weights.py`` and the model family's leaves), and runs the
+causal LM over whole sequences (prompt plus served tokens) in float32,
+with every matmul of the configuration's numerics:
 
 * ``posit_quant``: both operands rounded to Posit<16,1> (round to
   nearest, ties to the even pattern, saturating at minpos and maxpos),
@@ -18,6 +18,10 @@ The control computes the same model with every matmul operand rounded
 to float8 e4m3 instead (scaled per row of activations and per column of
 weights), the next precision below the configuration's 16-bit weights
 and activations.
+
+A model family (``families/<family>.py``) gives its layer's equations
+and leaves; the numerics, attention, the embedding and output head and
+the loop over sequences are here, shared by every family.
 
 Each sequence runs alone (attention over one sequence's positions, so
 the scores of a 2.5k-token sequence take under a gigabyte), padded at
@@ -34,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from weights import layer_weights, seed_key, top_weights
+from weights import seed_key, top_weights
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -136,17 +140,14 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-@partial(jax.jit, static_argnames=("model", "mode", "control"))
-def layer_forward(x, w, *, model, mode, control):
-    """One decoder layer over one sequence's rows x [T, d] (norm scales
-    are ones)."""
-    m = dict(model)
+def attention(xn, w, m: dict, mm):
+    """Causal grouped-query attention with RoPE over one sequence's
+    normed rows xn [T, d], through ``w["wq"]``, ``wk``, ``wv`` and the
+    output projection ``wo``; ``mm`` is the matmul under the numerics."""
     h, kv, hd = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
-    eps, theta = m["rms_norm_eps"], m["rope_theta"]
-    t = x.shape[0]
+    theta = m["rope_theta"]
+    t = xn.shape[0]
     pos = jnp.arange(t)
-    mm = partial(matmul, mode=mode, control=control)
-    xn = rmsnorm(x, eps)
     q = rope(mm(xn, w["wq"]).reshape(t, h, hd), pos, theta)
     k = rope(mm(xn, w["wk"]).reshape(t, kv, hd), pos, theta)
     v = mm(xn, w["wv"]).reshape(t, kv, hd)
@@ -155,10 +156,7 @@ def layer_forward(x, w, *, model, mode, control):
     s = jnp.where((pos[None, :] <= pos[:, None])[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("kgqs,skh->qkgh", p, v, precision=HIGHEST).reshape(t, h * hd)
-    x = x + mm(o, w["wo"])
-    xn = rmsnorm(x, eps)
-    up = jax.nn.silu(mm(xn, w["wg"])) * mm(xn, w["wu"])
-    return x + mm(up, w["wd"])
+    return mm(o, w["wo"])
 
 
 @partial(jax.jit, static_argnames=("model", "mode", "control"))
@@ -167,9 +165,9 @@ def head_forward(x, unembed, *, model, mode, control):
     return matmul(xn, unembed, mode, control)
 
 
-@partial(jax.jit, static_argnames=("model", "dtype"))
-def _layer_weights(key, layer, *, model, dtype):
-    w = layer_weights(dict(model), key, layer, jnp.dtype(dtype))
+@partial(jax.jit, static_argnames=("model", "dtype", "leaves"))
+def _layer_weights(key, layer, *, model, dtype, leaves):
+    w = leaves(dict(model), key, layer, jnp.dtype(dtype))
     return {name: leaf.astype(F32) for (_, name), leaf in w.items()}
 
 
@@ -183,14 +181,16 @@ def _bucket(n: int) -> int:
     return max(ROW_BUCKET, -(-n // ROW_BUCKET) * ROW_BUCKET)
 
 
-def served_logits(cfg: dict, seed: int, seqs, controls=(False,)):
+def served_logits(cfg: dict, seed: int, seqs, controls, *, layer_forward, layer_weights):
     """Logits at every position that predicts a served token.
 
     ``seqs``: ``[(prompt, served)]`` token lists.  Returns, for each
     entry of ``controls``, a float32 array [sum of len(served), V] in
     the order of ``seqs``: row ``j`` of a sequence is the distribution
     of its served token ``j``, given the prompt and served tokens
-    before it.
+    before it.  The family gives ``layer_forward(x, w, *, model, mode,
+    control)``, one jitted layer over one sequence's rows, and
+    ``layer_weights(model, key, layer, dtype)``, that layer's leaves.
     """
     model = cfg["model"]
     frozen = tuple(sorted(model.items()))
@@ -207,7 +207,8 @@ def served_logits(cfg: dict, seed: int, seqs, controls=(False,)):
     xs = {(c, i): embed[jnp.asarray(tok)] for c in controls for i, tok in enumerate(tokens)}
     del embed
     for layer in range(model["num_hidden_layers"]):
-        w = _layer_weights(key, jnp.int32(layer), model=frozen, dtype=dtype)
+        w = _layer_weights(key, jnp.int32(layer), model=frozen, dtype=dtype,
+                           leaves=layer_weights)
         for c, i in xs:
             xs[c, i] = layer_forward(xs[c, i], w, model=frozen, mode=mode, control=c)
         del w

@@ -13,10 +13,12 @@ serving through ``ContinuousBatchingEngine.step``.  With ``--trace 1``
 the window is traced by the JAX profiler and the per-layer metrics are
 reported instead of the end-to-end ones.
 
-After the window the engine is freed, and a sample of the requests that
-finished in it is run through the plain float32 reference
-(``reference.py``): every served token's logit must lie within the
-configuration's ``max_logit_gap`` of the reference's best.
+The configuration's model family (``families/<family>.py``) gives the
+program's ModelConfig, the weights and the reference.  After the window
+the engine is freed, and a sample of the requests that finished in it
+is run through the family's plain float32 reference: every served
+token's logit must lie within the configuration's ``max_logit_gap`` of
+the reference's best.
 
 The last line of standard output is one JSON object.  The run exits
 non-zero without it when JAX finds no TPU or fewer chips than the cell
@@ -47,25 +49,6 @@ import arith  # noqa: E402
 from cell import Cell, load_cell, load_metric  # noqa: E402
 from loop import ClosedLoop, CompileCount, Window  # noqa: E402
 from traffic import Traffic, load_mix  # noqa: E402
-
-
-def model_config(conf: dict):
-    """The program's ModelConfig, built from the file's own sizes."""
-    from repro.configs.base import ModelConfig
-    from repro.core.policy import parse_cfg_spec, parse_policy
-
-    m = conf["model"]
-    policy = conf["numerics"]["policy"]
-    numerics = parse_policy(policy) if "=" in policy else parse_cfg_spec(policy)
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"], n_kv=m["num_key_value_heads"],
-        head_dim=m["head_dim"], d_ff=m["intermediate_size"], vocab=m["vocab_size"],
-        act=m["hidden_act"], glu=True, rope_theta=m["rope_theta"],
-        tie_embeddings=m["tie_word_embeddings"], numerics=numerics,
-        param_dtype=conf["dtypes"]["param"], act_dtype=conf["dtypes"]["act"],
-    )
 
 
 def serve_options(conf: dict, profile: bool):
@@ -124,7 +107,8 @@ def choose_sample(win: Window, seed: int, min_served: int):
     return pick
 
 
-def check(conf: dict, seed: int, win: Window, outputs: dict, control: bool) -> tuple:
+def check(family, conf: dict, seed: int, win: Window, outputs: dict,
+          control: bool) -> tuple:
     """Numbers compared, each ``(value, limit)``, and diagnostics.
 
     ``max_logit_gap``: over a sample of the requests finished in the
@@ -143,8 +127,8 @@ def check(conf: dict, seed: int, win: Window, outputs: dict, control: bool) -> t
     seqs = [(r.prompt, outputs[id(r)]) for r in sample]
     gap, extra = float("inf"), {"served_tokens_checked": sum(len(s) for _, s in seqs)}
     if seqs:
-        logits = reference.served_logits(conf, seed, seqs,
-                                         controls=(False, True) if control else (False,))
+        logits = family.served_logits(conf, seed, seqs,
+                                      controls=(False, True) if control else (False,))
         served = [t for _, out in seqs for t in out]
         gap = float(np.max(reference.served_token_gaps(logits[0], served)))
         if control:
@@ -162,15 +146,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
              require_tpu: bool = True, control: bool = False) -> dict:
     import jax
 
-    import weights
     from repro.serving import build_engine
 
-    conf = cell.config
+    conf, family = cell.config, cell.family
     compiles = CompileCount()
     marks = {"start": time.perf_counter()}
-    cfg = model_config(conf)
+    cfg = family.model_config(conf)
     params = jax.block_until_ready(
-        weights.make_params(conf["model"], seed, conf["dtypes"]["param"]))
+        family.make_params(conf["model"], seed, conf["dtypes"]["param"]))
     marks["weights"] = time.perf_counter()
     eng = build_engine(cfg, serve_options(conf, profile=trace), params=params)
     del params
@@ -212,7 +195,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     del loop, eng
     gc.collect()
     t_check = time.perf_counter()
-    numbers, extra = check(conf, seed, win, outputs, control)
+    numbers, extra = check(family, conf, seed, win, outputs, control)
     extra["check_s"] = time.perf_counter() - t_check
     # a gap that could not be read (nothing finished, or not finite) fails
     numbers = {k: (v if np.isfinite(v) else None, lim) for k, (v, lim) in numbers.items()}
@@ -226,7 +209,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             if m["name"] in values:
                 metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
     else:
-        ctx = dict(window=win, config=conf, trace=reduced, counters=window_counters,
+        ctx = dict(window=win, config=conf, family=family,
+                   sites=family.layer_sites(conf["model"]), trace=reduced,
+                   counters=window_counters,
                    peaks=arith.peaks(devices[0].device_kind) if require_tpu else None)
         for m in cell.per_layer:
             value = load_metric(m["name"]).read(ctx)

@@ -5,7 +5,9 @@ import pathlib
 import pytest
 
 import arith
+from cell import load_family
 
+DENSE = load_family("dense")
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
 PK = arith.peaks("TPU v5 lite")
 
@@ -15,22 +17,26 @@ def yi(layers=32):
     return dict(model, num_hidden_layers=layers)
 
 
+def sites(model):
+    return DENSE.layer_sites(model)
+
+
 def test_macs_per_token_at_yi_6b_widths():
     # q and o 4096x4096, k and v 4096x512, gate/up/down 4096x11008
     per_layer = 2 * 4096 * 4096 + 2 * 4096 * 512 + 3 * 4096 * 11008
     assert per_layer == 173_015_040
-    assert arith.layer_macs(yi()) == 32 * per_layer
-    assert arith.layer_macs(yi(8)) == 8 * per_layer
+    assert arith.layer_macs(sites(yi()), yi()) == 32 * per_layer
+    assert arith.layer_macs(sites(yi(8)), yi(8)) == 8 * per_layer
     assert arith.head_macs(yi()) == 4096 * 64000
 
 
 def test_token_flops_count_attention_over_the_keys():
     m = yi(8)
-    base = arith.token_flops(m, 0, logits=False)
-    assert base == 2 * arith.layer_macs(m)
+    base = arith.token_flops(sites(m), m, 0, logits=False)
+    assert base == 2 * arith.layer_macs(sites(m), m)
     # QK^T and PV: 2 FLOPs per MAC, 32 heads of 128, 8 layers
-    assert arith.token_flops(m, 100, logits=False) - base == 4 * 8 * 100 * 32 * 128
-    assert arith.token_flops(m, 0, logits=True) - base == 2 * 4096 * 64000
+    assert arith.token_flops(sites(m), m, 100, logits=False) - base == 4 * 8 * 100 * 32 * 128
+    assert arith.token_flops(sites(m), m, 0, logits=True) - base == 2 * 4096 * 64000
 
 
 def test_decode_matmul_is_bound_by_weight_bytes():
@@ -45,8 +51,8 @@ def test_decode_matmul_is_bound_by_weight_bytes():
 
 def test_one_decode_step_reads_the_weights_once():
     m = yi(8)
-    step = arith.program_matmul_least_s(m, 8, 8, PK)
-    weights = (arith.layer_macs(m) + arith.head_macs(m)) * 2
+    step = arith.program_matmul_least_s(sites(m), m, 8, 8, PK)
+    weights = (arith.layer_macs(sites(m), m) + arith.head_macs(m)) * 2
     assert weights / 819e9 < step < 1.01 * weights / 819e9
 
 

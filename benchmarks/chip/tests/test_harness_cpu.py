@@ -23,7 +23,8 @@ SECONDS = 2.0
 
 def tiny_cell():
     return cell.Cell("tiny", 1, cell.load_config(TESTS / "tiny_config.json"),
-                     TESTS / "tiny_mix.json", BENCH["end_to_end"], [])
+                     TESTS / "tiny_mix.json", BENCH["end_to_end"], [],
+                     cell.load_family("dense"))
 
 
 def run_tiny(seed, control=False):

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 import reference
 import weights
+from cell import load_family
 from repro.kernels.plam_matmul import plam_matmul
 from repro.numerics import PositSpec, decode, encode, quantize
 
@@ -68,10 +69,11 @@ def test_one_layer_remade_equals_its_slice_of_the_served_tree():
     model = {"num_hidden_layers": 3, "hidden_size": 32, "num_attention_heads": 4,
              "num_key_value_heads": 2, "head_dim": 8, "intermediate_size": 48,
              "vocab_size": 64}
-    tree = weights.make_params(model, 2**31 + 5, "bfloat16")
+    dense = load_family("dense")
+    tree = dense.make_params(model, 2**31 + 5, "bfloat16")
     key = weights.seed_key(2**31 + 5)
     for layer in range(3):
-        alone = weights.layer_weights(model, key, layer, jnp.bfloat16)
+        alone = dense.layer_weights(model, key, layer, jnp.bfloat16)
         for (group, name), leaf in alone.items():
             np.testing.assert_array_equal(
                 np.asarray(leaf, np.float32),

@@ -1,7 +1,9 @@
 """The traffic generator: determinism per seed, lengths, chunk widths."""
+import json
 import pathlib
 import statistics
 
+import cell
 import traffic
 
 TRAFFIC = pathlib.Path(__file__).resolve().parents[1] / "traffic"
@@ -21,7 +23,7 @@ def test_same_seed_same_requests():
 
 
 def test_lengths_stay_in_the_mix_bounds():
-    for mix in ("generate", "chat"):
+    for mix in ("generate", "chat", "prefill"):
         m = traffic.load_mix(TRAFFIC / f"{mix}.json")
         for r in requests(mix, 7):
             assert m.prompt.min <= len(r.prompt) <= m.prompt.max
@@ -66,3 +68,13 @@ def test_chunk_widths():
     assert g.chunk_widths(16, 64) == [16, 32, 48, 64]
     c = traffic.Traffic(traffic.load_mix(TRAFFIC / "chat.json"), 0, 64000)
     assert c.chunk_widths(16, 256) == list(range(16, 257, 16))
+    p = traffic.Traffic(traffic.load_mix(TRAFFIC / "prefill.json"), 0, 64000)
+    assert p.chunk_widths(16, 64) == [16, 32, 48, 64]
+
+
+def test_every_cell_s_longest_request_fits_a_slot():
+    bench = json.loads((TRAFFIC.parents[2] / "BENCHMARK.json").read_text())
+    for w in bench["workloads"]:
+        c = cell.load_cell(w["name"])
+        m = traffic.load_mix(c.mix_path)
+        assert m.prompt.max + m.output.max <= c.config["engine"]["max_seq_len"], w["name"]

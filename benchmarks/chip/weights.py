@@ -9,9 +9,8 @@ the configuration's parameter dtype: uniform bits map to floats by exact
 operations, so a layer remade alone is bit-identical to its slice of
 the stacked tree.
 
-The tree has the layout ``build_engine(params=...)`` takes for a dense
-decoder: ``embed``, ``layers/{ln1,attn/{wq,wk,wv,wo},ln2,mlp/{wg,wu,wd}}``
-stacked on a leading layer axis, ``ln_f`` and ``unembed``.
+Which leaves a layer has is its model family's (``families/``); the
+primitives here are shared by every family.
 """
 from __future__ import annotations
 
@@ -19,17 +18,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-# (group, name, fan_in key, shape keys); a shape key names a model size
-LAYER_LEAVES = (
-    ("attn", "wq", "d", ("d", "q")),
-    ("attn", "wk", "d", ("d", "kv")),
-    ("attn", "wv", "d", ("d", "kv")),
-    ("attn", "wo", "q", ("q", "d")),
-    ("mlp", "wg", "d", ("d", "ff")),
-    ("mlp", "wu", "d", ("d", "ff")),
-    ("mlp", "wd", "ff", ("ff", "d")),
-)
 
 
 def seed_key(seed: int):
@@ -40,45 +28,41 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, seed >> 32)
 
 
-def _sizes(m: dict) -> dict:
-    return {
-        "d": m["hidden_size"],
-        "q": m["num_attention_heads"] * m["head_dim"],
-        "kv": m["num_key_value_heads"] * m["head_dim"],
-        "ff": m["intermediate_size"],
-        "v": m["vocab_size"],
-    }
-
-
 def _uniform(key, shape, fan_in: int, dtype):
     half_width = math.sqrt(3.0 / fan_in)
     u = jax.random.uniform(key, shape, jnp.float32, -1.0, 1.0)
     return (u * half_width).astype(dtype)
 
 
-def layer_weights(model: dict, key, layer: int, dtype):
-    """One layer's leaves (no norms): ``{(group, name): array}``."""
-    sz = _sizes(model)
+def layer_leaves(leaves, sizes: dict, key, layer: int, dtype):
+    """One layer's leaves: ``{(group, name): array}``.
+
+    ``leaves``: ``(group, name, fan_in key, shape keys)`` in the order
+    that numbers their keys; a key names an entry of ``sizes``.
+    """
     lk = jax.random.fold_in(key, layer + 1)
     out = {}
-    for i, (group, name, fan, shape) in enumerate(LAYER_LEAVES):
+    for i, (group, name, fan, shape) in enumerate(leaves):
         out[(group, name)] = _uniform(
-            jax.random.fold_in(lk, i), tuple(sz[s] for s in shape), sz[fan], dtype
+            jax.random.fold_in(lk, i), tuple(sizes[s] for s in shape), sizes[fan], dtype
         )
     return out
 
 
 def top_weights(model: dict, key, dtype):
     """``embed`` [V, d] and ``unembed`` [d, V]."""
-    sz = _sizes(model)
+    d, v = model["hidden_size"], model["vocab_size"]
     tk = jax.random.fold_in(key, 0)
-    embed = _uniform(jax.random.fold_in(tk, 0), (sz["v"], sz["d"]), sz["d"], dtype)
-    unembed = _uniform(jax.random.fold_in(tk, 1), (sz["d"], sz["v"]), sz["d"], dtype)
+    embed = _uniform(jax.random.fold_in(tk, 0), (v, d), d, dtype)
+    unembed = _uniform(jax.random.fold_in(tk, 1), (d, v), d, dtype)
     return embed, unembed
 
 
-def make_params(model: dict, seed: int, dtype):
-    """The whole served tree, in one jitted call on the default device."""
+def decoder_params(model: dict, seed: int, dtype, layer_weights):
+    """A decoder's whole served tree, in one jitted call on the default
+    device: ``embed``, ``layers`` (``ln1``, ``ln2`` and each group of
+    ``layer_weights(model, key, layer, dtype)`` stacked on a leading
+    layer axis), ``ln_f`` and ``unembed``; norm scales are ones."""
     n_layers = model["num_hidden_layers"]
     d = model["hidden_size"]
     dtype = jnp.dtype(dtype)
@@ -88,10 +72,9 @@ def make_params(model: dict, seed: int, dtype):
             jnp.arange(n_layers)
         )
         tree = {"ln1": {"scale": jnp.ones((n_layers, d), dtype)},
-                "ln2": {"scale": jnp.ones((n_layers, d), dtype)},
-                "attn": {}, "mlp": {}}
+                "ln2": {"scale": jnp.ones((n_layers, d), dtype)}}
         for (group, name), leaf in layers.items():
-            tree[group][name] = leaf
+            tree.setdefault(group, {})[name] = leaf
         embed, unembed = top_weights(model, key, dtype)
         return {
             "embed": embed,
