@@ -5,6 +5,8 @@ whatever implements it.  ``model`` is a configuration file's ``model``
 section (Hugging Face key names); ``sites`` is the (K, N) of the
 matmuls one token passes through in one layer, as the configuration's
 model family gives them (``layer_sites(model)`` in ``families/``).
+Shares of a peak count the cell's ``chips``: a call's whole work, split
+evenly over them, against each chip's peak.
 """
 from __future__ import annotations
 
@@ -73,3 +75,16 @@ def decode_attention_bytes(model: dict, keys: int, block: int) -> int:
     kv, h, hd = model["num_key_value_heads"], model["num_attention_heads"], model["head_dim"]
     cached = math.ceil(keys / block) * block * kv * hd * 2 * BF16
     return model["num_hidden_layers"] * (cached + 2 * h * hd * BF16)
+
+
+def peak_share(work, seconds: float, peak_per_s: float, chips: int) -> float:
+    """Per cent of ``chips`` chips' peak that ``work`` over ``seconds`` reaches."""
+    return 100.0 * work / seconds / (chips * peak_per_s)
+
+
+def roofline_share(least, kernel_s: float, chips: int, per_s: float = 1.0) -> float:
+    """Per cent of a kernel's device time per chip (``kernel_s``, averaged
+    over the chips) that its work would take at the roofline, split
+    evenly over ``chips``: ``least`` seconds, or ``least`` bytes at
+    ``per_s``."""
+    return 100.0 * least / per_s / chips / kernel_s

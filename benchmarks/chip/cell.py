@@ -7,6 +7,10 @@ configuration (``configs/<config>.json``) and a traffic mix
 ``families/<family>.py``; each per-layer metric is
 ``metrics/<metric>.py``.  A new cell, and a new architecture, need only
 new files and entries.
+
+The configuration states the deployment: its ``engine`` section may give
+the tensor-parallel ways ``tp`` (1 where it gives none), and the
+workload's ``chips`` has to be the same number.
 """
 from __future__ import annotations
 
@@ -50,9 +54,13 @@ def load_cell(workload: str, bench_path=ROOT / "BENCHMARK.json") -> Cell:
         raise SystemExit(f"unknown workload {workload!r}; known: {names}")
     conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
     config = load_config(ROOT / conf["file"])
+    chips, tp = int(entry["chips"]), int(config["engine"].get("tp", 1))
+    if chips != tp:
+        raise ValueError(f"workload {workload!r} asks for {chips} chips, but configuration "
+                         f"{entry['config']!r} states tp={tp}: the two must agree")
     return Cell(
         name=workload,
-        chips=int(entry["chips"]),
+        chips=chips,
         config=config,
         mix_path=CHIP / "traffic" / f"{entry['traffic']}.json",
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
@@ -72,8 +80,8 @@ def _load_module(prefix: str, path: pathlib.Path):
 
 def load_family(name: str, directory: pathlib.Path = FAMILIES):
     """``<directory>/<name>.py``: ``model_config(conf)``,
-    ``make_params(model, seed, dtype)``, ``served_logits(conf, seed,
-    seqs, controls)`` and ``layer_sites(model)``."""
+    ``make_params(model, seed, dtype, placement)``, ``served_logits(conf,
+    seed, seqs, controls)`` and ``layer_sites(model)``."""
     path = pathlib.Path(directory) / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"model family {name!r}: no file {path}")

@@ -64,8 +64,8 @@ def layer_weights(model: dict, key, layer: int, dtype):
     return weights.layer_leaves(LAYER_LEAVES, sizes(model), key, layer, dtype)
 
 
-def make_params(model: dict, seed: int, dtype):
-    return weights.decoder_params(model, seed, dtype, layer_weights)
+def make_params(model: dict, seed: int, dtype, placement=None):
+    return weights.decoder_params(model, seed, dtype, layer_weights, placement)
 
 
 def layer_sites(model: dict):

@@ -157,8 +157,9 @@ class ClosedLoop:
         while any(h.state.name not in ("FINISHED", "CANCELLED") for h in hs):
             self.eng.step()
 
-    def run(self, seconds: float) -> Window:
-        """Fill every slot, then measure ``seconds`` (rounded up to a step)."""
+    def run(self, seconds: float, steps: Optional[int] = None) -> Window:
+        """Fill every slot, then measure ``seconds`` (rounded up to a step),
+        or exactly ``steps`` engine steps where that is given."""
         for c in range(self.traffic.mix.clients):
             self.submit(c)
         first = list(self.tracked)
@@ -166,12 +167,12 @@ class ClosedLoop:
             self.step()
         n_compiles = self.compiles.count
         t_open, c0 = self.clock(), counters(self.eng)
-        steps = []
+        log = []
         with _span("bench.window", self.spans):
             while True:
                 work = self.step()
-                steps.append(work)
-                if work.t1 - t_open >= seconds:
+                log.append(work)
+                if (len(log) == steps) if steps else (work.t1 - t_open >= seconds):
                     break
-        return Window(t_open, steps[-1].t1, self.tracked, steps, c0,
+        return Window(t_open, log[-1].t1, self.tracked, log, c0,
                       counters(self.eng), self.compiles.count - n_compiles)

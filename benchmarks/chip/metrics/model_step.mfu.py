@@ -1,5 +1,5 @@
 """Model step: model FLOPs of the window's work over the window's
-seconds, as a share of the chip's bf16 peak.
+seconds, as a share of the bf16 peak of the cell's chips.
 
 Per prompt token and per decoded token: 2 FLOPs per matmul MAC of the
 layers, plus attention (QK^T and PV) over the keys it attends, plus
@@ -24,4 +24,5 @@ def window_flops(sites, model, steps):
 def read(ctx):
     win = ctx["window"]
     flops = window_flops(ctx["sites"], ctx["config"]["model"], win.steps)
-    return 100.0 * flops / win.seconds / ctx["peaks"]["bf16_flops_per_s"]
+    return arith.peak_share(flops, win.seconds, ctx["peaks"]["bf16_flops_per_s"],
+                            ctx["chips"])

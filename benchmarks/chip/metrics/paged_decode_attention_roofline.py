@@ -2,7 +2,8 @@
 the HBM peak over the kernel's device time.
 
 Per decoded token: the K and V blocks that hold the keys it attends,
-and its query and output, in every layer (``arith.decode_attention_bytes``).
+and its query and output, in every layer (``arith.decode_attention_bytes``),
+split over the cell's chips, against the kernel's time per chip.
 """
 import arith
 
@@ -17,4 +18,5 @@ def read(ctx):
     model, block = conf["model"], conf["engine"]["block_size"]
     nbytes = sum(arith.decode_attention_bytes(model, keys, block)
                  for s in ctx["window"].steps for keys in s.decode)
-    return 100.0 * nbytes / ctx["peaks"]["hbm_bytes_per_s"] / kernel_s
+    return arith.roofline_share(nbytes, kernel_s, ctx["chips"],
+                                ctx["peaks"]["hbm_bytes_per_s"])

@@ -5,7 +5,8 @@ Per call the least time is the larger of 2*M*K*N at the bf16 peak and
 its posit16 weight and input bytes plus float32 output bytes at the HBM
 peak (``arith.matmul_least_s``).  A decode step multiplies ``max_slots``
 rows through every layer and the output head; a prompt chunk its padded
-width through the layers and one row through the head.
+width through the layers and one row through the head.  The least time
+is split over the cell's chips, against the kernel's time per chip.
 """
 import arith
 
@@ -26,4 +27,4 @@ def read(ctx):
             least += arith.program_matmul_least_s(sites, model, width, 1, pk)
         if s.decode:
             least += arith.program_matmul_least_s(sites, model, slots, slots, pk)
-    return 100.0 * least / kernel_s
+    return arith.roofline_share(least, kernel_s, ctx["chips"])

@@ -14,7 +14,10 @@ the window is traced by the JAX profiler and the per-layer metrics are
 reported instead of the end-to-end ones.
 
 The configuration's model family (``families/<family>.py``) gives the
-program's ModelConfig, the weights and the reference.  After the window
+program's ModelConfig, the weights and the reference.  A configuration
+whose engine states ``tp`` > 1 is served through the engine's
+tensor-parallel path on that many chips, with its weights built in the
+engine's parameter shardings.  After the window
 the engine is freed, and a sample of the requests that finished in it
 is run through the family's plain float32 reference: every served
 token's logit must lie within the configuration's ``max_logit_gap`` of
@@ -38,6 +41,7 @@ import pathlib  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from functools import partial  # noqa: E402
 
 CHIP = pathlib.Path(__file__).resolve().parent
 ROOT = CHIP.parents[1]
@@ -60,8 +64,21 @@ def serve_options(conf: dict, profile: bool):
         max_slots=e["max_slots"], max_seq_len=e["max_seq_len"],
         prefill_chunk=e["prefill_chunk"], use_kernel=e["use_kernel"],
         temperature=e["temperature"], cache_dtype=conf["dtypes"]["cache"],
-        prequantize=conf["numerics"]["prequantize"], profile=profile,
+        prequantize=conf["numerics"]["prequantize"], tp=e.get("tp", 1), profile=profile,
     )
+
+
+def placement(tp: int, devices):
+    """Where the weights are built: the default device at tp=1, else the
+    program's parameter rule on the ``(data=1, model=tp)`` mesh over the
+    first ``tp`` devices that the engine builds, so that its placement
+    moves nothing."""
+    if tp == 1:
+        return None
+    from repro.parallel.sharding import make_mesh, param_shardings
+
+    mesh = make_mesh((1, tp), ("data", "model"), devices=devices[:tp])
+    return partial(param_shardings, mesh)
 
 
 def end_to_end(win: Window) -> dict:
@@ -143,7 +160,11 @@ def check(family, conf: dict, seed: int, win: Window, outputs: dict,
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
-             require_tpu: bool = True, control: bool = False) -> dict:
+             require_tpu: bool = True, control: bool = False,
+             steps: int | None = None) -> dict:
+    """One run of ``cell``; ``steps`` ends the window after that many engine
+    steps instead of ``seconds`` (CPU tests, whose runs must finish the
+    same requests)."""
     import jax
 
     from repro.serving import build_engine
@@ -152,10 +173,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     compiles = CompileCount()
     marks = {"start": time.perf_counter()}
     cfg = family.model_config(conf)
-    params = jax.block_until_ready(
-        family.make_params(conf["model"], seed, conf["dtypes"]["param"]))
+    opts = serve_options(conf, profile=trace)
+    params = jax.block_until_ready(family.make_params(
+        conf["model"], seed, conf["dtypes"]["param"], placement(opts.tp, devices)))
     marks["weights"] = time.perf_counter()
-    eng = build_engine(cfg, serve_options(conf, profile=trace), params=params)
+    eng = build_engine(cfg, opts, params=params)
     del params
     jax.block_until_ready(eng.params)
     marks["engine"] = time.perf_counter()
@@ -171,7 +193,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir.name, profiler_options=opts)
-    win = loop.run(seconds)
+    win = loop.run(seconds, steps)
     setup_s = win.t_open - T_START
     marks["fill"] = win.t_open
     names = list(marks)
@@ -209,7 +231,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             if m["name"] in values:
                 metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
     else:
-        ctx = dict(window=win, config=conf, family=family,
+        ctx = dict(window=win, config=conf, family=family, chips=cell.chips,
                    sites=family.layer_sites(conf["model"]), trace=reduced,
                    counters=window_counters,
                    peaks=arith.peaks(devices[0].device_kind) if require_tpu else None)

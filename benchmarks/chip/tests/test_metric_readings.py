@@ -3,7 +3,8 @@
 Each metric file reads the recorded trace beside one fixed window of
 steps, the tiny configuration and the v5e peaks; the readings are
 pinned, so a change to the harness that moves what a metric reads from
-the same trace shows here.  ``data/tiny_tpu*.xplane.pb`` hold no
+the same trace shows here.  The readings are of one chip; over four, the
+shares of a peak read a quarter of them.  ``data/tiny_tpu*.xplane.pb`` hold no
 prefill-chunk program, so the prefill metric reads nothing from them.
 """
 import json
@@ -62,7 +63,7 @@ def contexts():
     conf = json.loads((TESTS / "tiny_config.json").read_text())
     win = window()
     dense = load_family("dense")
-    return {name: dict(window=win, config=conf, family=dense,
+    return {name: dict(window=win, config=conf, family=dense, chips=1,
                        sites=dense.layer_sites(conf["model"]),
                        trace=trace_reduce.reduce_file(str(DATA / f"{name}.xplane.pb")),
                        counters=(win.counters0, win.counters1),
@@ -77,3 +78,12 @@ def test_every_benchmark_metric_has_a_pinned_reading():
 @pytest.mark.parametrize("trace,metric", [(t, m) for t in READINGS for m in READINGS[t]])
 def test_metric_reads_its_pinned_value(contexts, trace, metric):
     assert load_metric(metric).read(contexts[trace]) == READINGS[trace][metric]
+
+
+PEAK_SHARES = ("model_step.mfu", "plam_matmul_roofline", "paged_decode_attention_roofline")
+
+
+@pytest.mark.parametrize("trace,metric", [(t, m) for t in READINGS for m in PEAK_SHARES])
+def test_shares_of_a_peak_over_four_chips_read_a_quarter(contexts, trace, metric):
+    four = dict(contexts[trace], chips=4)
+    assert load_metric(metric).read(four) == READINGS[trace][metric] / 4

@@ -58,11 +58,16 @@ def top_weights(model: dict, key, dtype):
     return embed, unembed
 
 
-def decoder_params(model: dict, seed: int, dtype, layer_weights):
-    """A decoder's whole served tree, in one jitted call on the default
-    device: ``embed``, ``layers`` (``ln1``, ``ln2`` and each group of
-    ``layer_weights(model, key, layer, dtype)`` stacked on a leading
-    layer axis), ``ln_f`` and ``unembed``; norm scales are ones."""
+def decoder_params(model: dict, seed: int, dtype, layer_weights, placement=None):
+    """A decoder's whole served tree, in one jitted call: ``embed``,
+    ``layers`` (``ln1``, ``ln2`` and each group of ``layer_weights(model,
+    key, layer, dtype)`` stacked on a leading layer axis), ``ln_f`` and
+    ``unembed``; norm scales are ones.
+
+    ``placement`` maps the tree's shapes to the shardings it is built in,
+    so that each chip makes only its own shard; None builds it on the
+    default device.  The bits do not depend on it: random bits are drawn
+    partitionably (``jax_threefry_partitionable``)."""
     n_layers = model["num_hidden_layers"]
     d = model["hidden_size"]
     dtype = jnp.dtype(dtype)
@@ -83,4 +88,7 @@ def decoder_params(model: dict, seed: int, dtype, layer_weights):
             "unembed": unembed,
         }
 
-    return jax.jit(build)(seed_key(seed))
+    key = seed_key(seed)
+    if placement is None:
+        return jax.jit(build)(key)
+    return jax.jit(build, out_shardings=placement(jax.eval_shape(build, key)))(key)
